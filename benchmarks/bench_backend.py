@@ -214,6 +214,8 @@ def main():
                     help="benchmark host/device only")
     ap.add_argument("--json", default=None)
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     import jax
     from repro.core.backend import get_backend

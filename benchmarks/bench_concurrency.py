@@ -330,6 +330,8 @@ def main():
                     help="CI smoke scale: 80 sessions, small store")
     ap.add_argument("--json", default=None)
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     if args.tiny:
         args.sessions = 80

@@ -174,6 +174,8 @@ def main():
                     help="also bench the bitpacked binary tier vs the "
                          "float tier on binarized pair data")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     import jax
 

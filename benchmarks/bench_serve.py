@@ -250,6 +250,8 @@ def main():
     ap.add_argument("--json", default=None,
                     help="also write a JSON record to this path")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     import jax
 

@@ -248,6 +248,8 @@ def main():
     ap.add_argument("--repeats", type=int, default=5)
     ap.add_argument("--keep-db", default=None)
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.full:
         args.n_masks = 22275
 

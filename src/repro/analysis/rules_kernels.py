@@ -25,14 +25,28 @@ def _first_kernel_ref(call: ast.Call) -> str | None:
     return None
 
 
+_GRID_SPECS = {"GridSpec", "PrefetchScalarGridSpec"}
+
+
 def _grid_arity(call: ast.Call, local_tuples: dict[str, int]) -> int | None:
+    """Index-map arity a pallas_call or grid spec demands: the grid's rank
+    plus ``num_scalar_prefetch`` (Pallas appends one ref per prefetched
+    scalar operand).  None when the grid or the prefetch count is not a
+    literal the checker can read."""
+    rank = prefetch = None
     for kw in call.keywords:
         if kw.arg == "grid":
             if isinstance(kw.value, ast.Tuple):
-                return len(kw.value.elts)
-            if isinstance(kw.value, ast.Name):
-                return local_tuples.get(kw.value.id)
-    return None
+                rank = len(kw.value.elts)
+            elif isinstance(kw.value, ast.Name):
+                rank = local_tuples.get(kw.value.id)
+        elif kw.arg == "num_scalar_prefetch":
+            prefetch = (kw.value.value
+                        if isinstance(kw.value, ast.Constant)
+                        and isinstance(kw.value.value, int) else -1)
+    if rank is None or prefetch == -1:
+        return None
+    return rank + (prefetch or 0)
 
 
 def _iter_blockspecs(call: ast.Call):
@@ -65,8 +79,10 @@ class KernelConstraintsRule(Rule):
 Invariant, three parts, applied to any module that defines `*_kernel`
 functions or issues pl.pallas_call:
 
-1. Every BlockSpec index_map lambda takes exactly len(grid) parameters.
-   Pallas hands the index map one program id per grid axis; an arity
+1. Every BlockSpec index_map lambda takes exactly len(grid) parameters,
+   plus num_scalar_prefetch when the specs sit in a
+   PrefetchScalarGridSpec.  Pallas hands the index map one program id per
+   grid axis, then one ref per scalar-prefetch operand; an arity
    mismatch is a TypeError at trace time on TPU but can silently slip
    through on interpret-mode-only CI runs when the call path is not
    exercised.
@@ -91,6 +107,11 @@ Violation examples:
     pl.pallas_call(f, grid=(b, h // bh),
                    in_specs=[pl.BlockSpec((1, bh), lambda i: (i, 0))], ...)
     # index map takes 1 arg, grid has rank 2
+
+    pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=(b,),
+        in_specs=[pl.BlockSpec((1, h), lambda i: (i, 0))])
+    # index map takes 1 arg; grid rank 1 + 1 prefetch operand = 2
 
     def cp_count_kernel(chi_ref, out_ref):
         if chi_ref[0, 0] > 0:        # traced value in Python `if`
@@ -125,6 +146,10 @@ src/repro/kernels/cp_count.py, mask_agg.py, pair_count.py, chi_build.py.
             ref = _first_kernel_ref(call)
             if ref:
                 kernel_names.add(ref)
+        grid_specs = [n for n in ast.walk(tree)
+                      if isinstance(n, ast.Call)
+                      and call_name(n) in _GRID_SPECS]
+        for call in pallas_calls + grid_specs:
             arity = _grid_arity(call, local_tuples)
             if arity is None:
                 continue
@@ -135,8 +160,9 @@ src/repro/kernels/cp_count.py, mask_agg.py, pair_count.py, chi_build.py.
                         self.name, lam,
                         f"BlockSpec index_map takes "
                         f"{len(lam.args.args)} argument(s) but the grid "
-                        f"has rank {arity} — Pallas passes one program "
-                        f"id per grid axis"))
+                        f"rank plus scalar-prefetch operands is {arity} "
+                        f"— Pallas passes one program id per grid axis, "
+                        f"then one ref per prefetched operand"))
 
         for fn in ast.walk(tree):
             if isinstance(fn, ast.FunctionDef) and fn.name in kernel_names:

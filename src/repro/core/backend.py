@@ -60,11 +60,12 @@ import numpy as np
 
 from ..kernels import ops as kops
 from ..obs.metrics import REGISTRY as _REG
+from .chi import gather_rows
 from .distributed import (_bounds_from_corners, device_resolve,
                           make_chi_bounds_step, make_cp_multi_packed_step,
                           make_cp_multi_step, make_fused_verify_step,
                           make_mask_agg_packed_step, make_mask_agg_step,
-                          make_mesh, make_pair_cells_step,
+                          make_pair_cells_step,
                           make_pair_counts_packed_step,
                           make_pair_counts_step, make_topk_select_step,
                           make_verify_packed_step, make_verify_step,
@@ -340,7 +341,7 @@ def _device_cp_bounds(tables, pos, rois, rb, cb, ks):
     The tier is implicit in the operands — ``device_resolve`` derives the
     grid from ``rb``'s length — so one compilation serves each tier shape."""
     corners, area = device_resolve(rois, rb, cb)
-    return _bounds_from_corners(tables[pos], corners, area,
+    return _bounds_from_corners(gather_rows(tables, pos), corners, area,
                                 ks[0], ks[1], ks[2], ks[3])
 
 
@@ -350,8 +351,8 @@ def _device_pair_cells(tables, pos_a, pos_b, ks, rois, rb, cb, stat):
     thresholded counts and the cell algebra all on device — the pair
     filter phase leaving the host like the CP leaf (DESIGN.md §13).
     ``ks`` holds [ka_in, ka_out, kb_in, kb_out] value-edge indices."""
-    tab_a = tables[pos_a]
-    tab_b = tables[pos_b]
+    tab_a = gather_rows(tables, pos_a)
+    tab_b = gather_rows(tables, pos_b)
     lo_a = cell_counts_jnp(tab_a, ks[0])
     hi_a = cell_counts_jnp(tab_a, ks[1])
     lo_b = cell_counts_jnp(tab_b, ks[2])
@@ -599,7 +600,8 @@ class MeshBackend(_KthValueMixin, ExecBackend):
         self.store = store
         self.cfg = store.cfg
         if mesh is None:
-            mesh = make_mesh((len(jax.devices()),), ("data",))
+            mesh = jax.make_mesh((len(jax.devices()),), ("data",),
+                                 axis_types=(jax.sharding.AxisType.Auto,))
         self.mesh = mesh
         self.n_dev = int(mesh.devices.size)
         self._masks = store.resident_masks()

@@ -4,7 +4,8 @@ The paper's prototype is single-node; this module is the beyond-paper
 scale-out.  The mask DB (mask bytes + CHI tables + ROI table) is sharded
 row-wise over every mesh axis (a DB of N masks becomes N/num_devices rows per
 chip).  Four device-side *step* functions cover the engine's hot paths; each
-is jit-compiled with explicit shardings and is what the multi-pod dry-run
+is jit-compiled with explicit shardings (steps that launch a Pallas kernel
+run it per shard under ``jax.shard_map``) and is what the multi-pod dry-run
 lowers for the "masksearch" cells:
 
   * ``filter_bounds_step`` — CHI bounds + predicate verdicts for every local
@@ -34,8 +35,6 @@ except the query descriptor scalars.
 
 from __future__ import annotations
 
-from typing import Sequence
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -45,26 +44,6 @@ from ..kernels import ops as kops
 from . import chi as chi_lib
 from . import cp as cp_lib
 from .exprs import cell_counts_jnp, pair_cell_bounds_jnp
-
-# shard_map moved out of jax.experimental (and check_rep became check_vma)
-# across the jax versions this repo supports; resolve once here.
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-    _SHARD_MAP_KW = {"check_vma": False}
-else:                                      # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SHARD_MAP_KW = {"check_rep": False}
-
-
-def make_mesh(shape: Sequence[int], axes: Sequence[str]) -> Mesh:
-    """Version-portable ``jax.make_mesh`` (``axis_types`` where supported)."""
-    try:
-        return jax.make_mesh(
-            tuple(shape), tuple(axes),
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    except (TypeError, AttributeError):
-        return jax.make_mesh(tuple(shape), tuple(axes))
-
 
 def db_axes(mesh: Mesh) -> tuple[str, ...]:
     """All mesh axes — DB rows shard over the full device set."""
@@ -77,6 +56,14 @@ def row_sharding(mesh: Mesh, ndim: int) -> NamedSharding:
 
 def replicated(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P())
+
+
+def _per_shard(mesh: Mesh, fn, in_specs, out_specs):
+    """``jit(shard_map(fn))``: every device runs ``fn`` on its own rows.
+    A Mosaic kernel cannot be partitioned automatically, so each kernel
+    step goes through here rather than a sharded ``jax.jit``."""
+    return jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                                 out_specs=out_specs, check_vma=False))
 
 
 # ---------------------------------------------------------------------------
@@ -169,17 +156,9 @@ def make_verify_step(mesh: Mesh):
     the portable fallback (identical semantics — see kernels/ops.py).
     """
     axes = db_axes(mesh)
-
-    def step(masks, rois, lv, uv):
-        return kops.cp_count(masks, rois, lv, uv)
-
-    return jax.jit(
-        step,
-        in_shardings=(NamedSharding(mesh, P(axes, None, None)),
-                      NamedSharding(mesh, P(axes, None)),
-                      replicated(mesh), replicated(mesh)),
-        out_shardings=NamedSharding(mesh, P(axes)),
-    )
+    return _per_shard(mesh, kops.cp_count,
+                      (P(axes, None, None), P(axes, None), P(), P()),
+                      P(axes))
 
 
 def make_topk_step(mesh: Mesh, k: int, desc: bool = True):
@@ -213,12 +192,12 @@ def make_topk_step(mesh: Mesh, k: int, desc: bool = True):
         survivors = score_opt >= tau
         return gathered_opt, gathered_ids, tau, survivors
 
-    mapped = _shard_map(
+    mapped = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(axes, None, None, None), P(axes, None), P(), P(), P(),
                   P(axes)),
         out_specs=(P(), P(), P(), P(axes)),
-        **_SHARD_MAP_KW,
+        check_vma=False,
     )
     return jax.jit(mapped), n_dev * k
 
@@ -290,9 +269,9 @@ def make_topk_select_step(mesh: Mesh, k: int):
         order = jax.lax.top_k(g_vals, k)[1]
         return g_ids[order[k - 1]]
 
-    mapped = _shard_map(local, mesh=mesh,
-                        in_specs=(P(axes), P(axes), P(axes)),
-                        out_specs=P(), **_SHARD_MAP_KW)
+    mapped = jax.shard_map(local, mesh=mesh,
+                           in_specs=(P(axes), P(axes), P(axes)),
+                           out_specs=P(), check_vma=False)
     return jax.jit(mapped)
 
 
@@ -306,17 +285,9 @@ def make_mask_agg_step(mesh: Mesh):
       → (inter (G,), union (G,)) int32.
     """
     axes = db_axes(mesh)
-
-    def step(group_masks, rois, thresh):
-        return kops.mask_agg_counts(group_masks, rois, thresh)
-
-    row = NamedSharding(mesh, P(axes))
-    return jax.jit(
-        step,
-        in_shardings=(NamedSharding(mesh, P(axes, None, None, None)),
-                      NamedSharding(mesh, P(axes, None)), replicated(mesh)),
-        out_shardings=(row, row),
-    )
+    return _per_shard(mesh, kops.mask_agg_counts,
+                      (P(axes, None, None, None), P(axes, None), P()),
+                      (P(axes), P(axes)))
 
 
 def make_cp_multi_step(mesh: Mesh):
@@ -328,17 +299,9 @@ def make_cp_multi_step(mesh: Mesh):
       → counts (Q,B) int32.
     """
     axes = db_axes(mesh)
-
-    def step(masks, rois, lvs, uvs):
-        return kops.cp_count_multi(masks, rois, lvs, uvs)
-
-    return jax.jit(
-        step,
-        in_shardings=(NamedSharding(mesh, P(axes, None, None)),
-                      NamedSharding(mesh, P(None, axes, None)),
-                      replicated(mesh), replicated(mesh)),
-        out_shardings=NamedSharding(mesh, P(None, axes)),
-    )
+    return _per_shard(mesh, kops.cp_count_multi,
+                      (P(axes, None, None), P(None, axes, None), P(), P()),
+                      P(None, axes))
 
 
 def make_pair_counts_step(mesh: Mesh):
@@ -353,19 +316,10 @@ def make_pair_counts_step(mesh: Mesh):
       → (inter (B,), union (B,), diff (B,)) int32.
     """
     axes = db_axes(mesh)
-
-    def step(masks_a, masks_b, rois, ta, tb):
-        return kops.pair_counts(masks_a, masks_b, rois, ta, tb)
-
-    row = NamedSharding(mesh, P(axes))
-    rep = replicated(mesh)
-    return jax.jit(
-        step,
-        in_shardings=(NamedSharding(mesh, P(axes, None, None)),
-                      NamedSharding(mesh, P(axes, None, None)),
-                      NamedSharding(mesh, P(axes, None)), rep, rep),
-        out_shardings=(row, row, row),
-    )
+    return _per_shard(mesh, kops.pair_counts,
+                      (P(axes, None, None), P(axes, None, None),
+                       P(axes, None), P(), P()),
+                      (P(axes), P(axes), P(axes)))
 
 
 def make_pair_cells_step(mesh: Mesh, stat: str):
@@ -404,8 +358,8 @@ def make_pair_cells_step(mesh: Mesh, stat: str):
 
 
 # -- bitpacked binary-mask tier (DESIGN.md §12) -----------------------------
-# Packed variants of the verification steps: identical shardings (the word
-# axis replaces the pixel-column axis, rank for rank), kernel dispatch
+# Packed variants of the verification steps: identical partition specs (the
+# word axis replaces the pixel-column axis, rank for rank), kernel dispatch
 # swapped for the popcount family.  Pair/agg thresholds are float32 — the
 # packed wrappers derive integer flags from them; the uint32 words never
 # meet a float lane.
@@ -418,17 +372,9 @@ def make_verify_packed_step(mesh: Mesh):
       → counts (V,) int32.
     """
     axes = db_axes(mesh)
-
-    def step(packed, rois, lv, uv):
-        return kops.cp_count_packed(packed, rois, lv, uv)
-
-    return jax.jit(
-        step,
-        in_shardings=(NamedSharding(mesh, P(axes, None, None)),
-                      NamedSharding(mesh, P(axes, None)),
-                      replicated(mesh), replicated(mesh)),
-        out_shardings=NamedSharding(mesh, P(axes)),
-    )
+    return _per_shard(mesh, kops.cp_count_packed,
+                      (P(axes, None, None), P(axes, None), P(), P()),
+                      P(axes))
 
 
 def make_cp_multi_packed_step(mesh: Mesh):
@@ -438,17 +384,9 @@ def make_cp_multi_packed_step(mesh: Mesh):
       → counts (Q,B) int32.
     """
     axes = db_axes(mesh)
-
-    def step(packed, rois, lvs, uvs):
-        return kops.cp_count_multi_packed(packed, rois, lvs, uvs)
-
-    return jax.jit(
-        step,
-        in_shardings=(NamedSharding(mesh, P(axes, None, None)),
-                      NamedSharding(mesh, P(None, axes, None)),
-                      replicated(mesh), replicated(mesh)),
-        out_shardings=NamedSharding(mesh, P(None, axes)),
-    )
+    return _per_shard(mesh, kops.cp_count_multi_packed,
+                      (P(axes, None, None), P(None, axes, None), P(), P()),
+                      P(None, axes))
 
 
 def make_mask_agg_packed_step(mesh: Mesh):
@@ -458,17 +396,9 @@ def make_mask_agg_packed_step(mesh: Mesh):
       → (inter (G,), union (G,)) int32.
     """
     axes = db_axes(mesh)
-
-    def step(group_packed, rois, thresh):
-        return kops.mask_agg_counts_packed(group_packed, rois, thresh)
-
-    row = NamedSharding(mesh, P(axes))
-    return jax.jit(
-        step,
-        in_shardings=(NamedSharding(mesh, P(axes, None, None, None)),
-                      NamedSharding(mesh, P(axes, None)), replicated(mesh)),
-        out_shardings=(row, row),
-    )
+    return _per_shard(mesh, kops.mask_agg_counts_packed,
+                      (P(axes, None, None, None), P(axes, None), P()),
+                      (P(axes), P(axes)))
 
 
 def make_pair_counts_packed_step(mesh: Mesh):
@@ -479,19 +409,10 @@ def make_pair_counts_packed_step(mesh: Mesh):
       → (inter (B,), union (B,), diff (B,)) int32.
     """
     axes = db_axes(mesh)
-
-    def step(packed_a, packed_b, rois, ta, tb):
-        return kops.pair_counts_packed(packed_a, packed_b, rois, ta, tb)
-
-    row = NamedSharding(mesh, P(axes))
-    rep = replicated(mesh)
-    return jax.jit(
-        step,
-        in_shardings=(NamedSharding(mesh, P(axes, None, None)),
-                      NamedSharding(mesh, P(axes, None, None)),
-                      NamedSharding(mesh, P(axes, None)), rep, rep),
-        out_shardings=(row, row, row),
-    )
+    return _per_shard(mesh, kops.pair_counts_packed,
+                      (P(axes, None, None), P(axes, None, None),
+                       P(axes, None), P(), P()),
+                      (P(axes), P(axes), P(axes)))
 
 
 def make_fused_verify_step(mesh: Mesh):
@@ -505,19 +426,11 @@ def make_fused_verify_step(mesh: Mesh):
       → counts (Q,B) int32.
     """
     axes = db_axes(mesh)
-
-    def step(packed, rois, lvs, uvs, decided, lb):
-        return kops.fused_bounds_verify(packed, rois, lvs, uvs, decided, lb)
-
-    rep = replicated(mesh)
-    qb = NamedSharding(mesh, P(None, axes))
-    return jax.jit(
-        step,
-        in_shardings=(NamedSharding(mesh, P(axes, None, None)),
-                      NamedSharding(mesh, P(None, axes, None)),
-                      rep, rep, qb, qb),
-        out_shardings=qb,
-    )
+    qb = P(None, axes)
+    return _per_shard(mesh, kops.fused_bounds_verify,
+                      (P(axes, None, None), P(None, axes, None), P(), P(),
+                       qb, qb),
+                      qb)
 
 
 def make_iou_agg_step(mesh: Mesh):

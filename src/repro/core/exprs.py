@@ -552,9 +552,10 @@ def _interval_binop(op, llb, lub, rlb, rub):
         return cands.min(0), cands.max(0)
     if op == "/":
         # CP counts are >= 0; we only support non-negative denominators
-        # (true for all paper queries).  den lb == 0 → upper bound +inf.
+        # (true for all paper queries).  den lb == 0 → upper bound +inf,
+        # and lower bound 0: the exact quotient is 0 when the den is 0.
         with np.errstate(divide="ignore", invalid="ignore"):
-            lb = np.where(rub > 0, llb / rub, 0.0)
+            lb = np.where(rlb > 0, llb / rub, 0.0)
             ub = np.where(rlb > 0, lub / rlb, np.where(lub > 0, _INF, 0.0))
         return lb, ub
     raise ValueError(f"unknown op {op}")
@@ -663,7 +664,7 @@ class MaskEvalContext:
             cfg, table = self.cfg, self.store.chi_table
         else:
             cfg, table = self.cfg.for_grid(g), self.store.chi_tier_table(g)
-        table = table[jnp.asarray(self.positions)]
+        table = chi_lib.gather_rows(table, self.positions)
         lb, ub = chi_lib.chi_bounds(table, cfg, rois, node.lv, node.uv)
         return np.asarray(lb, np.float64), np.asarray(ub, np.float64)
 

@@ -47,7 +47,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import packing
-from .chi import CHIConfig, build_chi_delta, build_chi_np, tier_slice
+from .chi import (CHIConfig, build_chi_delta, build_chi_np, gather_rows,
+                  scatter_rows, tier_slice)
 
 # Paper's EBS gp3 provisioning (§4): 125 MiB/s, 3000 IOPS.
 EBS_THROUGHPUT_BYTES_S = 125 * 1024 * 1024
@@ -663,8 +664,7 @@ class MaskStore:
             self._chi_chunks[c] = patched
         self._chi_cat = None
         if self._chi_dev is not None:
-            self._chi_dev = self._chi_dev.at[jnp.asarray(positions)].set(
-                jnp.asarray(new_rows))
+            self._chi_dev = scatter_rows(self._chi_dev, positions, new_rows)
         # pyramid tiers / value stats: patch materialized caches in place
         # (copy-on-write, same as the owning chunks above)
         for g, tab in list(self._chi_tier_host.items()):
@@ -672,8 +672,8 @@ class MaskStore:
             patched_t[positions] = tier_slice(new_rows, self.cfg.grid, g)
             self._chi_tier_host[g] = patched_t
         for g, tab in list(self._chi_tier_dev.items()):
-            self._chi_tier_dev[g] = tab.at[jnp.asarray(positions)].set(
-                jnp.asarray(tier_slice(new_rows, self.cfg.grid, g)))
+            self._chi_tier_dev[g] = scatter_rows(
+                tab, positions, tier_slice(new_rows, self.cfg.grid, g))
         if self._chi_stats is not None:
             stats = self._chi_stats.copy()
             stats[positions] = new_rows[:, -1, -1, :]
@@ -736,12 +736,12 @@ class MaskStore:
         self._chi_chunks = [np.ascontiguousarray(self.chi_host()[keep])]
         self._chi_cat = None
         if self._chi_dev is not None:
-            self._chi_dev = self._chi_dev[jnp.asarray(keep_idx)]
+            self._chi_dev = gather_rows(self._chi_dev, keep_idx)
         # pyramid tiers / value stats: gather survivors
         for g, tab in list(self._chi_tier_host.items()):
             self._chi_tier_host[g] = np.ascontiguousarray(tab[keep])
         for g, tab in list(self._chi_tier_dev.items()):
-            self._chi_tier_dev[g] = tab[jnp.asarray(keep_idx)]
+            self._chi_tier_dev[g] = gather_rows(tab, keep_idx)
         if self._chi_stats is not None:
             self._chi_stats = np.ascontiguousarray(self._chi_stats[keep])
         # mask bytes

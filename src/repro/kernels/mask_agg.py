@@ -8,7 +8,9 @@ count into one pass, emitting two scalars per group.
 
 Tiling: grid ``(N, H/bh)``; block ``(1, S, bh, W)`` — all S member masks of a
 group stream together (S is small: 2–8 mask types).  Intersection is a min-
-reduce over the type axis, union a max-reduce; both stay in VMEM.
+reduce over the type axis, union a max-reduce; both stay in VMEM.  ROI
+corners and the threshold are SMEM scalars (scalar prefetch); both counts
+accumulate into one whole-array SMEM output.
 """
 
 from __future__ import annotations
@@ -18,30 +20,28 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .cp_count import _pick_bh
+from .cp_count import SMEM_OUT, _over_rows, _pick_bh, roi_inside, value_scalars
 
 
-def _agg_kernel(roi_ref, thresh_ref, masks_ref, inter_ref, union_ref, *,
-                bh: int, w: int):
-    row_tile = pl.program_id(1)
+def _agg_kernel(rois_ref, t_ref, masks_ref, out_ref, *, bh: int, w: int,
+                nb: int):
+    i, row_tile = pl.program_id(0), pl.program_id(1)
 
     @pl.when(row_tile == 0)
     def _init():
-        inter_ref[0] = 0
-        union_ref[0] = 0
+        out_ref[i] = 0
+        out_ref[nb + i] = 0
 
-    m = masks_ref[0]                                   # (S, bh, W)
-    t = thresh_ref[0]
-    binary = (m > t).astype(jnp.int32)
+    m = masks_ref[0].astype(jnp.float32)               # (S, bh, W)
+    binary = (m > t_ref[0]).astype(jnp.int32)
     inter = jnp.min(binary, axis=0)                    # AND over mask types
     union = jnp.max(binary, axis=0)                    # OR  over mask types
-    r0, c0, r1, c1 = roi_ref[0, 0], roi_ref[0, 1], roi_ref[0, 2], roi_ref[0, 3]
-    rr = jax.lax.broadcasted_iota(jnp.int32, (bh, w), 0) + row_tile * bh
-    cc = jax.lax.broadcasted_iota(jnp.int32, (bh, w), 1)
-    inside = ((rr >= r0) & (rr < r1) & (cc >= c0) & (cc < c1)).astype(jnp.int32)
-    inter_ref[0] += jnp.sum(inter * inside)
-    union_ref[0] += jnp.sum(union * inside)
+    inside = roi_inside(rois_ref, 4 * i, (bh, w),
+                        row_tile * bh).astype(jnp.int32)
+    out_ref[i] += jnp.sum(inter * inside)
+    out_ref[nb + i] += jnp.sum(union * inside)
 
 
 def mask_agg_counts_pallas(group_masks: jax.Array, rois: jax.Array, thresh, *,
@@ -50,20 +50,23 @@ def mask_agg_counts_pallas(group_masks: jax.Array, rois: jax.Array, thresh, *,
     n, s, h, w = group_masks.shape
     bh = _pick_bh(h, w, group_masks.dtype.itemsize,
                   budget_bytes=2 * 1024 * 1024 // max(s, 1))
-    grid = (n, h // bh)
-    thresh = jnp.asarray(thresh, group_masks.dtype).reshape(1)
-    kernel = functools.partial(_agg_kernel, bh=bh, w=w)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 4), lambda i, j: (i, 0)),
-            pl.BlockSpec((1,), lambda i, j: (0,)),
-            pl.BlockSpec((1, s, bh, w), lambda i, j: (i, 0, j, 0)),
-        ],
-        out_specs=(pl.BlockSpec((1,), lambda i, j: (i,)),
-                   pl.BlockSpec((1,), lambda i, j: (i,))),
-        out_shape=(jax.ShapeDtypeStruct((n,), jnp.int32),
-                   jax.ShapeDtypeStruct((n,), jnp.int32)),
-        interpret=interpret,
-    )(rois.astype(jnp.int32), thresh, group_masks)
+    thresh = value_scalars(thresh, group_masks.dtype)
+    rois = rois.astype(jnp.int32)
+
+    def call(lo, hi):
+        nb = hi - lo
+        kernel = functools.partial(_agg_kernel, bh=bh, w=w, nb=nb)
+        out = pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2,
+                grid=(nb, h // bh),
+                in_specs=[pl.BlockSpec((1, s, bh, w),
+                                       lambda i, j, r, t: (i, 0, j, 0))],
+                out_specs=SMEM_OUT),
+            out_shape=jax.ShapeDtypeStruct((2 * nb,), jnp.int32),
+            interpret=interpret,
+        )(rois[lo:hi].reshape(-1), thresh, group_masks[lo:hi])
+        return tuple(out.reshape(2, nb))
+
+    return _over_rows(call, n, 6)

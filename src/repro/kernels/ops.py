@@ -1,14 +1,17 @@
 """Jit'd public wrappers for the Pallas kernels, with portable fallbacks.
 
-Dispatch policy: the Pallas path is used on TPU backends (or when
-``interpret=True`` is forced, e.g. in tests); every other backend gets the
-pure-jnp reference, which is semantically identical.  Shape contracts that
-the kernels can't serve (ragged CHI grids) also fall back.
+Dispatch policy: by default the compiled Pallas path runs on TPU backends
+and every other backend gets the pure-jnp reference, which is semantically
+identical.  Interpret mode runs only when a caller asks for it
+(``interpret=True``); ``use_pallas=True`` off the TPU without
+``interpret=True`` raises instead of silently interpreting.  Shape
+contracts that the kernels can't serve (ragged CHI grids) fall back to the
+reference.
 
-Setting ``REPRO_FORCE_PALLAS_INTERPRET=1`` in the environment forces every
-wrapper onto the Pallas path in interpret mode — CI uses this to exercise
-the actual kernel bodies on CPU machines instead of only the jnp
-references.
+Setting ``REPRO_FORCE_PALLAS_INTERPRET=1`` in the environment asks for
+interpret mode in every default-dispatched wrapper — CI uses this to
+exercise the actual kernel bodies on CPU machines instead of only the jnp
+references.  It is a test knob: ``chip_smoke.py`` refuses to run with it.
 
 These wrappers are what core/ and the distributed engine call — nothing else
 imports the kernel modules directly.
@@ -47,30 +50,22 @@ _JIT_COMPILES = _REG.counter(
 
 def _instrument(name: str, fn):
     """Wrap a jitted kernel entry point with launch counting, dispatch
-    timing, and recompile detection (via the jit cache-size delta, absent
-    on older jax — then the compile counter just stays 0)."""
+    timing, and recompile detection (via the jit cache-size delta)."""
     launches = _KERNEL_LAUNCHES.labels(kernel=name)
     seconds = _KERNEL_SECONDS.labels(kernel=name)
     compiles = _JIT_COMPILES.labels(kernel=name)
 
-    def _cache_size() -> int:
-        sz = getattr(fn, "_cache_size", None)
-        try:
-            return int(sz()) if callable(sz) else -1
-        except Exception:
-            return -1
-
     @functools.wraps(fn)
     def wrapper(*args, **kw):
-        before = _cache_size()
+        before = fn._cache_size()
         t0 = time.perf_counter()
         try:
             return fn(*args, **kw)
         finally:
             seconds.observe(time.perf_counter() - t0)
             launches.inc()
-            after = _cache_size()
-            if 0 <= before < after:
+            after = fn._cache_size()
+            if before < after:
                 compiles.inc(after - before)
 
     return wrapper
@@ -83,14 +78,21 @@ def _on_tpu() -> bool:
 def _dispatch(use_pallas: bool | None, interpret: bool) -> tuple[bool, bool]:
     """Resolve the (pallas, interpret) pair for one wrapper call.
 
-    The force flag only overrides the *default* dispatch — a caller that
-    explicitly asked for the jnp reference (``use_pallas=False``) keeps it,
-    so reference-vs-Pallas comparison tests stay meaningful under the
+    ``interpret=True`` selects the Pallas path in interpret mode unless the
+    caller explicitly asked for the jnp reference (``use_pallas=False``).
+    The force flag only overrides the *default* dispatch, so
+    reference-vs-Pallas comparison tests stay meaningful under the
     forced-interpret CI leg."""
-    if _FORCE_INTERPRET and use_pallas is None:
+    if use_pallas is False:
+        return False, False
+    if interpret or (_FORCE_INTERPRET and use_pallas is None):
         return True, True
-    pallas = _on_tpu() if use_pallas is None else use_pallas
-    return pallas, interpret
+    if use_pallas and not _on_tpu():
+        raise ValueError(
+            f"use_pallas=True needs a TPU backend (found "
+            f"{jax.default_backend()!r}); pass interpret=True to run the "
+            f"kernel in interpret mode")
+    return _on_tpu(), False
 
 
 @functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))
@@ -98,9 +100,9 @@ def cp_count(masks, rois, lv, uv, *, use_pallas: bool | None = None,
              interpret: bool = False):
     """Batched exact CP — (B,H,W), (B,4) → (B,) int32."""
     pallas, interpret = _dispatch(use_pallas, interpret)
-    if pallas or interpret:
+    if pallas:
         return cp_count_pallas(masks, rois, lv, uv,
-                               interpret=interpret or not _on_tpu())
+                               interpret=interpret)
     return ref.cp_count_ref(masks, rois, lv, uv)
 
 
@@ -109,9 +111,9 @@ def cp_count_multi(masks, rois, lvs, uvs, *, use_pallas: bool | None = None,
                    interpret: bool = False):
     """Multi-query CP — (B,H,W), (Q,B,4), (Q,), (Q,) → (Q,B) int32."""
     pallas, interpret = _dispatch(use_pallas, interpret)
-    if pallas or interpret:
+    if pallas:
         return cp_count_multi_pallas(masks, rois, lvs, uvs,
-                                     interpret=interpret or not _on_tpu())
+                                     interpret=interpret)
     return ref.cp_count_multi_ref(masks, rois, lvs, uvs)
 
 
@@ -122,10 +124,9 @@ def chi_cell_hist(masks, interior_edges, grid: int, *,
     _, h, w = masks.shape
     divisible = (h % grid == 0) and (w % grid == 0)
     pallas, interpret = _dispatch(use_pallas, interpret)
-    pallas = pallas and divisible
-    if (pallas or interpret) and divisible:
+    if pallas and divisible:
         return chi_cell_hist_pallas(masks, interior_edges, grid,
-                                    interpret=interpret or not _on_tpu())
+                                    interpret=interpret)
     return ref.chi_cell_hist_ref(masks, interior_edges, grid)
 
 
@@ -134,9 +135,9 @@ def mask_agg_counts(group_masks, rois, thresh, *,
                     use_pallas: bool | None = None, interpret: bool = False):
     """Fused MASK_AGG counts — (N,S,H,W), (N,4) → (inter, union) int32."""
     pallas, interpret = _dispatch(use_pallas, interpret)
-    if pallas or interpret:
+    if pallas:
         return mask_agg_counts_pallas(group_masks, rois, thresh,
-                                      interpret=interpret or not _on_tpu())
+                                      interpret=interpret)
     return ref.mask_agg_counts_ref(group_masks, rois, thresh)
 
 
@@ -146,9 +147,9 @@ def pair_counts(masks_a, masks_b, rois, ta, tb, *,
     """Fused dual-mask counts — (B,H,W)×2, (B,4) → (inter, union, diff),
     each (B,) int32, in one pass over both masks (DESIGN.md §9)."""
     pallas, interpret = _dispatch(use_pallas, interpret)
-    if pallas or interpret:
+    if pallas:
         return pair_counts_pallas(masks_a, masks_b, rois, ta, tb,
-                                  interpret=interpret or not _on_tpu())
+                                  interpret=interpret)
     return ref.pair_counts_ref(masks_a, masks_b, rois, ta, tb)
 
 
@@ -161,9 +162,9 @@ def cp_count_packed(packed, rois, lv, uv, *, use_pallas: bool | None = None,
     """Batched exact CP on packed words — (B,H,words) uint32, (B,4) →
     (B,) int32, bit-identical to ``cp_count`` on the same binary masks."""
     pallas, interpret = _dispatch(use_pallas, interpret)
-    if pallas or interpret:
+    if pallas:
         return popcount.cp_count_packed_pallas(
-            packed, rois, lv, uv, interpret=interpret or not _on_tpu())
+            packed, rois, lv, uv, interpret=interpret)
     return popcount.cp_count_packed_ref(packed, rois, lv, uv)
 
 
@@ -173,9 +174,9 @@ def cp_count_multi_packed(packed, rois, lvs, uvs, *,
                           interpret: bool = False):
     """Multi-query CP on packed words — (B,H,words), (Q,B,4) → (Q,B)."""
     pallas, interpret = _dispatch(use_pallas, interpret)
-    if pallas or interpret:
+    if pallas:
         return popcount.cp_count_multi_packed_pallas(
-            packed, rois, lvs, uvs, interpret=interpret or not _on_tpu())
+            packed, rois, lvs, uvs, interpret=interpret)
     return popcount.cp_count_multi_packed_ref(packed, rois, lvs, uvs)
 
 
@@ -186,9 +187,9 @@ def mask_agg_counts_packed(group_packed, rois, thresh, *,
     """Fused MASK_AGG counts on packed words — (N,S,H,words), (N,4) →
     (inter, union) int32."""
     pallas, interpret = _dispatch(use_pallas, interpret)
-    if pallas or interpret:
+    if pallas:
         return popcount.mask_agg_counts_packed_pallas(
-            group_packed, rois, thresh, interpret=interpret or not _on_tpu())
+            group_packed, rois, thresh, interpret=interpret)
     return popcount.mask_agg_counts_packed_ref(group_packed, rois, thresh)
 
 
@@ -199,10 +200,10 @@ def pair_counts_packed(packed_a, packed_b, rois, ta, tb, *,
     """Fused dual-mask counts on packed words — (B,H,words)×2, (B,4) →
     (inter, union, diff), each (B,) int32."""
     pallas, interpret = _dispatch(use_pallas, interpret)
-    if pallas or interpret:
+    if pallas:
         return popcount.pair_counts_packed_pallas(
             packed_a, packed_b, rois, ta, tb,
-            interpret=interpret or not _on_tpu())
+            interpret=interpret)
     return popcount.pair_counts_packed_ref(packed_a, packed_b, rois, ta, tb)
 
 
@@ -215,10 +216,10 @@ def fused_bounds_verify(packed, rois, lvs, uvs, decided, lb, *,
     counting the undecided remainder from the packed words.  (B,H,words),
     (Q,B,4), (Q,), (Q,), (Q,B), (Q,B) → (Q,B) int32."""
     pallas, interpret = _dispatch(use_pallas, interpret)
-    if pallas or interpret:
+    if pallas:
         return popcount.fused_verify_packed_pallas(
             packed, rois, lvs, uvs, decided, lb,
-            interpret=interpret or not _on_tpu())
+            interpret=interpret)
     return popcount.fused_verify_packed_ref(packed, rois, lvs, uvs,
                                             decided, lb)
 

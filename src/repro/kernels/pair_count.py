@@ -11,8 +11,9 @@ budget a single-mask CP pays.
 
 Tiling mirrors ``cp_count``: grid ``(B, H/bh)``; each step loads one
 ``(1, bh, W)`` tile of each mask (lane dimension = W kept whole), builds
-the ROI predicate from ``broadcasted_iota``, and accumulates the three
-counts into (1,)-blocked outputs across the sequential row-tile axis.
+the ROI predicate from ``broadcasted_iota`` and the SMEM ROI scalars, and
+accumulates the three counts into one whole-array SMEM output across the
+sequential row-tile axis.
 """
 
 from __future__ import annotations
@@ -22,31 +23,26 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .cp_count import _pick_bh
+from .cp_count import SMEM_OUT, _over_rows, _pick_bh, roi_inside, value_scalars
 
 
-def _pair_kernel(roi_ref, a_ref, b_ref, ta_ref, tb_ref,
-                 inter_ref, union_ref, diff_ref, *, bh: int, w: int):
-    row_tile = pl.program_id(1)
+def _pair_kernel(rois_ref, t_ref, a_ref, b_ref, out_ref, *, bh: int, w: int,
+                 nb: int):
+    i, row_tile = pl.program_id(0), pl.program_id(1)
 
     @pl.when(row_tile == 0)
     def _init():
-        inter_ref[0] = 0
-        union_ref[0] = 0
-        diff_ref[0] = 0
+        for k in range(3):
+            out_ref[k * nb + i] = 0
 
-    a = a_ref[0]                                      # (bh, W)
-    b = b_ref[0]
-    ba = a > ta_ref[0]
-    bb = b > tb_ref[0]
-    r0, c0, r1, c1 = roi_ref[0, 0], roi_ref[0, 1], roi_ref[0, 2], roi_ref[0, 3]
-    rr = jax.lax.broadcasted_iota(jnp.int32, (bh, w), 0) + row_tile * bh
-    cc = jax.lax.broadcasted_iota(jnp.int32, (bh, w), 1)
-    inside = (rr >= r0) & (rr < r1) & (cc >= c0) & (cc < c1)
-    inter_ref[0] += jnp.sum((inside & ba & bb).astype(jnp.int32))
-    union_ref[0] += jnp.sum((inside & (ba | bb)).astype(jnp.int32))
-    diff_ref[0] += jnp.sum((inside & ba & ~bb).astype(jnp.int32))
+    ba = a_ref[0].astype(jnp.float32) > t_ref[0]      # (bh, W)
+    bb = b_ref[0].astype(jnp.float32) > t_ref[1]
+    inside = roi_inside(rois_ref, 4 * i, (bh, w), row_tile * bh)
+    out_ref[i] += jnp.sum((inside & ba & bb).astype(jnp.int32))
+    out_ref[nb + i] += jnp.sum((inside & (ba | bb)).astype(jnp.int32))
+    out_ref[2 * nb + i] += jnp.sum((inside & ba & ~bb).astype(jnp.int32))
 
 
 def pair_counts_pallas(masks_a: jax.Array, masks_b: jax.Array,
@@ -59,30 +55,24 @@ def pair_counts_pallas(masks_a: jax.Array, masks_b: jax.Array,
     """
     b, h, w = masks_a.shape
     bh = _pick_bh(h, w, masks_a.dtype.itemsize)
-    grid = (b, h // bh)
-    ta = jnp.asarray(ta, masks_a.dtype).reshape(1)
-    tb = jnp.asarray(tb, masks_b.dtype).reshape(1)
-    kernel = functools.partial(_pair_kernel, bh=bh, w=w)
-    out = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 4), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, bh, w), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, bh, w), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1,), lambda i, j: (0,)),
-            pl.BlockSpec((1,), lambda i, j: (0,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1,), lambda i, j: (i,)),
-            pl.BlockSpec((1,), lambda i, j: (i,)),
-            pl.BlockSpec((1,), lambda i, j: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b,), jnp.int32),
-            jax.ShapeDtypeStruct((b,), jnp.int32),
-            jax.ShapeDtypeStruct((b,), jnp.int32),
-        ],
-        interpret=interpret,
-    )(rois.astype(jnp.int32), masks_a, masks_b, ta, tb)
-    return tuple(out)
+    thresh = jnp.concatenate([value_scalars(ta, masks_a.dtype),
+                              value_scalars(tb, masks_b.dtype)])
+    rois = rois.astype(jnp.int32)
+
+    def call(s, e):
+        nb = e - s
+        kernel = functools.partial(_pair_kernel, bh=bh, w=w, nb=nb)
+        tile = pl.BlockSpec((1, bh, w), lambda i, j, r, t: (i, j, 0))
+        out = pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2,
+                grid=(nb, h // bh),
+                in_specs=[tile, tile],
+                out_specs=SMEM_OUT),
+            out_shape=jax.ShapeDtypeStruct((3 * nb,), jnp.int32),
+            interpret=interpret,
+        )(rois[s:e].reshape(-1), thresh, masks_a[s:e], masks_b[s:e])
+        return tuple(out.reshape(3, nb))
+
+    return _over_rows(call, b, 7)

@@ -40,8 +40,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .cp_count import _pick_bh
+from .cp_count import SMEM_OUT, _over_rows, _pick_bh
 
 _WORD = 32
 
@@ -90,14 +91,21 @@ def _thresh_flags(t):
     return (t < 1.0).astype(jnp.int32), (t < 0.0).astype(jnp.int32)
 
 
-def _tile_valid(roi_row, bh, nw, row_tile):
+def _tile_valid(rois_ref, base, bh, nw, row_tile):
     """Per-word ROI coverage for one (bh, nw) tile: uint32 span masks on
-    rows inside [r0, r1), zero elsewhere."""
-    r0, c0, r1, c1 = roi_row[0], roi_row[1], roi_row[2], roi_row[3]
+    rows inside [r0, r1), zero elsewhere.  The corners are SMEM scalars
+    at ``base``."""
+    r0, c0, r1, c1 = (rois_ref[base + k] for k in range(4))
     rr = jax.lax.broadcasted_iota(jnp.int32, (bh, nw), 0) + row_tile * bh
-    base = jax.lax.broadcasted_iota(jnp.int32, (bh, nw), 1) * _WORD
-    span = _span_mask(c0 - base, c1 - base)
+    base_col = jax.lax.broadcasted_iota(jnp.int32, (bh, nw), 1) * _WORD
+    span = _span_mask(c0 - base_col, c1 - base_col)
     return jnp.where((rr >= r0) & (rr < r1), span, jnp.uint32(0))
+
+
+def _tile_spec(bh, nw):
+    """``(1, bh, words)`` tile of one batch row, for a rank-2 grid with two
+    scalar-prefetch operands."""
+    return pl.BlockSpec((1, bh, nw), lambda i, j, r, f: (i, j, 0))
 
 
 # ---------------------------------------------------------------------------
@@ -105,19 +113,19 @@ def _tile_valid(roi_row, bh, nw, row_tile):
 # ---------------------------------------------------------------------------
 
 
-def _cp_popcount_kernel(roi_ref, f1_ref, f0_ref, mask_ref, out_ref, *,
+def _cp_popcount_kernel(rois_ref, flags_ref, mask_ref, out_ref, *,
                         bh: int, nw: int):
-    row_tile = pl.program_id(1)
+    i, row_tile = pl.program_id(0), pl.program_id(1)
 
     @pl.when(row_tile == 0)
     def _init():
-        out_ref[0] = 0
+        out_ref[i] = 0
 
     m = mask_ref[0]                                   # (bh, nw) uint32
-    valid = _tile_valid(roi_ref[0], bh, nw, row_tile)
+    valid = _tile_valid(rois_ref, 4 * i, bh, nw, row_tile)
     ones = jnp.sum(_popcount32(m & valid))
     area = jnp.sum(_popcount32(valid))
-    out_ref[0] += f1_ref[0] * ones + f0_ref[0] * (area - ones)
+    out_ref[i] += flags_ref[0] * ones + flags_ref[1] * (area - ones)
 
 
 def cp_count_packed_pallas(packed: jax.Array, rois: jax.Array, lv, uv, *,
@@ -125,38 +133,39 @@ def cp_count_packed_pallas(packed: jax.Array, rois: jax.Array, lv, uv, *,
     """(B, H, words) uint32, (B, 4) → (B,) int32 exact CP counts."""
     b, h, nw = packed.shape
     bh = _pick_bh(h, nw, packed.dtype.itemsize)
-    grid = (b, h // bh)
-    f1, f0 = _range_flags(lv, uv)
+    flags = jnp.stack(_range_flags(lv, uv))
+    rois = rois.astype(jnp.int32)
     kernel = functools.partial(_cp_popcount_kernel, bh=bh, nw=nw)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 4), lambda i, j: (i, 0)),
-            pl.BlockSpec((1,), lambda i, j: (0,)),
-            pl.BlockSpec((1,), lambda i, j: (0,)),
-            pl.BlockSpec((1, bh, nw), lambda i, j: (i, j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((b,), jnp.int32),
-        interpret=interpret,
-    )(rois.astype(jnp.int32), f1.reshape(1), f0.reshape(1), packed)
+
+    def call(s, e):
+        return pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2, grid=(e - s, h // bh),
+                in_specs=[_tile_spec(bh, nw)], out_specs=SMEM_OUT),
+            out_shape=jax.ShapeDtypeStruct((e - s,), jnp.int32),
+            interpret=interpret,
+        )(rois[s:e].reshape(-1), flags, packed[s:e])
+
+    return _over_rows(call, b, 5)
 
 
-def _cp_multi_popcount_kernel(rois_ref, f1s_ref, f0s_ref, mask_ref, out_ref,
-                              *, bh: int, nw: int, q: int):
-    row_tile = pl.program_id(1)
+def _cp_multi_popcount_kernel(rois_ref, flags_ref, mask_ref, out_ref, *,
+                              bh: int, nw: int, q: int, nb: int):
+    i, row_tile = pl.program_id(0), pl.program_id(1)
 
     @pl.when(row_tile == 0)
     def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
+        for qi in range(q):
+            out_ref[qi * nb + i] = 0
 
     m = mask_ref[0]                                   # (bh, nw) — loaded ONCE
     for qi in range(q):                               # static unroll over Q
-        valid = _tile_valid(rois_ref[qi, 0], bh, nw, row_tile)
+        valid = _tile_valid(rois_ref, 4 * (qi * nb + i), bh, nw, row_tile)
         ones = jnp.sum(_popcount32(m & valid))
         area = jnp.sum(_popcount32(valid))
-        out_ref[qi, 0] += f1s_ref[qi] * ones + f0s_ref[qi] * (area - ones)
+        out_ref[qi * nb + i] += (flags_ref[qi] * ones
+                                 + flags_ref[q + qi] * (area - ones))
 
 
 def cp_count_multi_packed_pallas(packed: jax.Array, rois: jax.Array,
@@ -166,45 +175,47 @@ def cp_count_multi_packed_pallas(packed: jax.Array, rois: jax.Array,
     b, h, nw = packed.shape
     q = rois.shape[0]
     bh = _pick_bh(h, nw, packed.dtype.itemsize)
-    grid = (b, h // bh)
-    f1s, f0s = _range_flags(lvs, uvs)
-    kernel = functools.partial(_cp_multi_popcount_kernel, bh=bh, nw=nw, q=q)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((q, 1, 4), lambda i, j: (0, i, 0)),
-            pl.BlockSpec((q,), lambda i, j: (0,)),
-            pl.BlockSpec((q,), lambda i, j: (0,)),
-            pl.BlockSpec((1, bh, nw), lambda i, j: (i, j, 0)),
-        ],
-        out_specs=pl.BlockSpec((q, 1), lambda i, j: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((q, b), jnp.int32),
-        interpret=interpret,
-    )(rois.astype(jnp.int32), f1s, f0s, packed)
+    flags = jnp.concatenate(_range_flags(lvs, uvs))   # [f1s; f0s]
+    rois = rois.astype(jnp.int32)
+
+    def call(s, e):
+        nb = e - s
+        kernel = functools.partial(_cp_multi_popcount_kernel, bh=bh, nw=nw,
+                                   q=q, nb=nb)
+        out = pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2, grid=(nb, h // bh),
+                in_specs=[_tile_spec(bh, nw)], out_specs=SMEM_OUT),
+            out_shape=jax.ShapeDtypeStruct((q * nb,), jnp.int32),
+            interpret=interpret,
+        )(rois[:, s:e].reshape(-1), flags, packed[s:e])
+        return out.reshape(q, nb)
+
+    return _over_rows(call, b, 5 * q)
 
 
-def _agg_popcount_kernel(roi_ref, f1_ref, f0_ref, masks_ref,
-                         inter_ref, union_ref, *, bh: int, nw: int, s: int):
-    row_tile = pl.program_id(1)
+def _agg_popcount_kernel(rois_ref, flags_ref, masks_ref, out_ref, *,
+                         bh: int, nw: int, s: int, nb: int):
+    i, row_tile = pl.program_id(0), pl.program_id(1)
 
     @pl.when(row_tile == 0)
     def _init():
-        inter_ref[0] = 0
-        union_ref[0] = 0
+        out_ref[i] = 0
+        out_ref[nb + i] = 0
 
     m = masks_ref[0]                                  # (S, bh, nw) uint32
-    f1 = f1_ref[0]
-    f0 = f0_ref[0]
+    f1 = flags_ref[0]
+    f0 = flags_ref[1]
     inter_w = _effective_word(m[0], f1, f0)
     union_w = inter_w
     for si in range(1, s):                            # static unroll over S
         eff = _effective_word(m[si], f1, f0)
         inter_w = inter_w & eff
         union_w = union_w | eff
-    valid = _tile_valid(roi_ref[0], bh, nw, row_tile)
-    inter_ref[0] += jnp.sum(_popcount32(inter_w & valid))
-    union_ref[0] += jnp.sum(_popcount32(union_w & valid))
+    valid = _tile_valid(rois_ref, 4 * i, bh, nw, row_tile)
+    out_ref[i] += jnp.sum(_popcount32(inter_w & valid))
+    out_ref[nb + i] += jnp.sum(_popcount32(union_w & valid))
 
 
 def mask_agg_counts_packed_pallas(group_packed: jax.Array, rois: jax.Array,
@@ -213,43 +224,43 @@ def mask_agg_counts_packed_pallas(group_packed: jax.Array, rois: jax.Array,
     n, s, h, nw = group_packed.shape
     bh = _pick_bh(h, nw, group_packed.dtype.itemsize,
                   budget_bytes=2 * 1024 * 1024 // max(s, 1))
-    grid = (n, h // bh)
-    f1, f0 = _thresh_flags(thresh)
-    kernel = functools.partial(_agg_popcount_kernel, bh=bh, nw=nw, s=s)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 4), lambda i, j: (i, 0)),
-            pl.BlockSpec((1,), lambda i, j: (0,)),
-            pl.BlockSpec((1,), lambda i, j: (0,)),
-            pl.BlockSpec((1, s, bh, nw), lambda i, j: (i, 0, j, 0)),
-        ],
-        out_specs=(pl.BlockSpec((1,), lambda i, j: (i,)),
-                   pl.BlockSpec((1,), lambda i, j: (i,))),
-        out_shape=(jax.ShapeDtypeStruct((n,), jnp.int32),
-                   jax.ShapeDtypeStruct((n,), jnp.int32)),
-        interpret=interpret,
-    )(rois.astype(jnp.int32), f1.reshape(1), f0.reshape(1), group_packed)
+    flags = jnp.stack(_thresh_flags(thresh))
+    rois = rois.astype(jnp.int32)
+
+    def call(lo, hi):
+        nb = hi - lo
+        kernel = functools.partial(_agg_popcount_kernel, bh=bh, nw=nw, s=s,
+                                   nb=nb)
+        out = pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2, grid=(nb, h // bh),
+                in_specs=[pl.BlockSpec((1, s, bh, nw),
+                                       lambda i, j, r, f: (i, 0, j, 0))],
+                out_specs=SMEM_OUT),
+            out_shape=jax.ShapeDtypeStruct((2 * nb,), jnp.int32),
+            interpret=interpret,
+        )(rois[lo:hi].reshape(-1), flags, group_packed[lo:hi])
+        return tuple(out.reshape(2, nb))
+
+    return _over_rows(call, n, 6)
 
 
-def _pair_popcount_kernel(roi_ref, fa_ref, fb_ref, a_ref, b_ref,
-                          inter_ref, union_ref, diff_ref, *,
-                          bh: int, nw: int):
-    row_tile = pl.program_id(1)
+def _pair_popcount_kernel(rois_ref, flags_ref, a_ref, b_ref, out_ref, *,
+                          bh: int, nw: int, nb: int):
+    i, row_tile = pl.program_id(0), pl.program_id(1)
 
     @pl.when(row_tile == 0)
     def _init():
-        inter_ref[0] = 0
-        union_ref[0] = 0
-        diff_ref[0] = 0
+        for k in range(3):
+            out_ref[k * nb + i] = 0
 
-    ea = _effective_word(a_ref[0], fa_ref[0], fa_ref[1])
-    eb = _effective_word(b_ref[0], fb_ref[0], fb_ref[1])
-    valid = _tile_valid(roi_ref[0], bh, nw, row_tile)
-    inter_ref[0] += jnp.sum(_popcount32(ea & eb & valid))
-    union_ref[0] += jnp.sum(_popcount32((ea | eb) & valid))
-    diff_ref[0] += jnp.sum(_popcount32(ea & ~eb & valid))
+    ea = _effective_word(a_ref[0], flags_ref[0], flags_ref[1])
+    eb = _effective_word(b_ref[0], flags_ref[2], flags_ref[3])
+    valid = _tile_valid(rois_ref, 4 * i, bh, nw, row_tile)
+    out_ref[i] += jnp.sum(_popcount32(ea & eb & valid))
+    out_ref[nb + i] += jnp.sum(_popcount32((ea | eb) & valid))
+    out_ref[2 * nb + i] += jnp.sum(_popcount32(ea & ~eb & valid))
 
 
 def pair_counts_packed_pallas(packed_a: jax.Array, packed_b: jax.Array,
@@ -258,53 +269,47 @@ def pair_counts_packed_pallas(packed_a: jax.Array, packed_b: jax.Array,
     """(B,H,words)×2, (B,4) → (inter, union, diff) each (B,) int32."""
     b, h, nw = packed_a.shape
     bh = _pick_bh(h, nw, packed_a.dtype.itemsize)
-    grid = (b, h // bh)
-    fa = jnp.stack(_thresh_flags(ta))
-    fb = jnp.stack(_thresh_flags(tb))
-    kernel = functools.partial(_pair_popcount_kernel, bh=bh, nw=nw)
-    out = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 4), lambda i, j: (i, 0)),
-            pl.BlockSpec((2,), lambda i, j: (0,)),
-            pl.BlockSpec((2,), lambda i, j: (0,)),
-            pl.BlockSpec((1, bh, nw), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, bh, nw), lambda i, j: (i, j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1,), lambda i, j: (i,)),
-            pl.BlockSpec((1,), lambda i, j: (i,)),
-            pl.BlockSpec((1,), lambda i, j: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b,), jnp.int32),
-            jax.ShapeDtypeStruct((b,), jnp.int32),
-            jax.ShapeDtypeStruct((b,), jnp.int32),
-        ],
-        interpret=interpret,
-    )(rois.astype(jnp.int32), fa, fb, packed_a, packed_b)
-    return tuple(out)
+    flags = jnp.stack(_thresh_flags(ta) + _thresh_flags(tb))
+    rois = rois.astype(jnp.int32)
+
+    def call(s, e):
+        nb = e - s
+        kernel = functools.partial(_pair_popcount_kernel, bh=bh, nw=nw, nb=nb)
+        out = pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2, grid=(nb, h // bh),
+                in_specs=[_tile_spec(bh, nw), _tile_spec(bh, nw)],
+                out_specs=SMEM_OUT),
+            out_shape=jax.ShapeDtypeStruct((3 * nb,), jnp.int32),
+            interpret=interpret,
+        )(rois[s:e].reshape(-1), flags, packed_a[s:e], packed_b[s:e])
+        return tuple(out.reshape(3, nb))
+
+    return _over_rows(call, b, 7)
 
 
-def _fused_verify_popcount_kernel(rois_ref, f1s_ref, f0s_ref, dec_ref,
-                                  lb_ref, mask_ref, out_ref, *,
-                                  bh: int, nw: int, q: int):
-    row_tile = pl.program_id(1)
+def _fused_verify_popcount_kernel(rois_ref, flags_ref, dec_ref, lb_ref,
+                                  mask_ref, out_ref, *,
+                                  bh: int, nw: int, q: int, nb: int):
+    i, row_tile = pl.program_id(0), pl.program_id(1)
 
     @pl.when(row_tile == 0)
     def _init():
         # CHI-decided (descriptor, mask) entries pass their exact bound
         # straight through; undecided entries start at 0 and accumulate.
-        out_ref[...] = dec_ref[...] * lb_ref[...]
+        for qi in range(q):
+            k = qi * nb + i
+            out_ref[k] = dec_ref[k] * lb_ref[k]
 
     m = mask_ref[0]                                   # (bh, nw) — loaded ONCE
     for qi in range(q):                               # static unroll over Q
-        valid = _tile_valid(rois_ref[qi, 0], bh, nw, row_tile)
+        k = qi * nb + i
+        valid = _tile_valid(rois_ref, 4 * k, bh, nw, row_tile)
         ones = jnp.sum(_popcount32(m & valid))
         area = jnp.sum(_popcount32(valid))
-        count = f1s_ref[qi] * ones + f0s_ref[qi] * (area - ones)
-        out_ref[qi, 0] += (1 - dec_ref[qi, 0]) * count
+        count = flags_ref[qi] * ones + flags_ref[q + qi] * (area - ones)
+        out_ref[k] += (1 - dec_ref[k]) * count
 
 
 def fused_verify_packed_pallas(packed: jax.Array, rois: jax.Array,
@@ -322,26 +327,29 @@ def fused_verify_packed_pallas(packed: jax.Array, rois: jax.Array,
     b, h, nw = packed.shape
     q = rois.shape[0]
     bh = _pick_bh(h, nw, packed.dtype.itemsize)
-    grid = (b, h // bh)
-    f1s, f0s = _range_flags(lvs, uvs)
-    kernel = functools.partial(_fused_verify_popcount_kernel, bh=bh, nw=nw,
-                               q=q)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((q, 1, 4), lambda i, j: (0, i, 0)),
-            pl.BlockSpec((q,), lambda i, j: (0,)),
-            pl.BlockSpec((q,), lambda i, j: (0,)),
-            pl.BlockSpec((q, 1), lambda i, j: (0, i)),
-            pl.BlockSpec((q, 1), lambda i, j: (0, i)),
-            pl.BlockSpec((1, bh, nw), lambda i, j: (i, j, 0)),
-        ],
-        out_specs=pl.BlockSpec((q, 1), lambda i, j: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((q, b), jnp.int32),
-        interpret=interpret,
-    )(rois.astype(jnp.int32), f1s, f0s, decided.astype(jnp.int32),
-      lb.astype(jnp.int32), packed)
+    flags = jnp.concatenate(_range_flags(lvs, uvs))   # [f1s; f0s]
+    rois = rois.astype(jnp.int32)
+    decided = decided.astype(jnp.int32)
+    lb = lb.astype(jnp.int32)
+
+    def call(s, e):
+        nb = e - s
+        kernel = functools.partial(_fused_verify_popcount_kernel, bh=bh,
+                                   nw=nw, q=q, nb=nb)
+        out = pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=4, grid=(nb, h // bh),
+                in_specs=[pl.BlockSpec((1, bh, nw),
+                                       lambda i, j, r, f, d, lo: (i, j, 0))],
+                out_specs=SMEM_OUT),
+            out_shape=jax.ShapeDtypeStruct((q * nb,), jnp.int32),
+            interpret=interpret,
+        )(rois[:, s:e].reshape(-1), flags, decided[:, s:e].reshape(-1),
+          lb[:, s:e].reshape(-1), packed[s:e])
+        return out.reshape(q, nb)
+
+    return _over_rows(call, b, 8 * q)
 
 
 # ---------------------------------------------------------------------------

@@ -11,13 +11,16 @@ from __future__ import annotations
 
 import jax
 
-from ..core.distributed import make_mesh
+
+def _auto_mesh(shape, axes):
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_local_mesh(shape=None, axes=None):
@@ -25,7 +28,7 @@ def make_local_mesh(shape=None, axes=None):
     n = len(jax.devices())
     if shape is None:
         shape, axes = (n,), ("data",)
-    return make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 # TPU v5e constants used by the roofline analysis (assignment-provided).

@@ -603,6 +603,8 @@ def main(argv=None):
                     help="max admitted requests per execute_many batch")
     ap.add_argument("--max-inflight-batches", type=int, default=2)
     args = ap.parse_args(argv)
+    from ..compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from .server import _synthetic_store
     if args.root:

@@ -275,19 +275,33 @@ def make_server(service: MaskSearchService, host: str = "127.0.0.1",
     return ThreadingHTTPServer((host, port), handler)
 
 
-def _synthetic_store(n: int, size: int):
-    from ..core import CHIConfig, MaskStore
+def synthetic_db(n: int, size: int, *, seed: int = 0):
+    """The synthetic deployment's data, made from ``seed``: ``n`` float32
+    ``size``×``size`` saliency masks, two mask types per image (1 =
+    saliency, 2 = attention), per-mask object boxes, ``CHIConfig(grid=16,
+    num_bins=16)``.  → (masks, meta, rois, cfg)."""
+    from ..core import CHIConfig
     from ..core.store import MASK_META_DTYPE
     from ..data.masks import object_boxes, saliency_masks
-    rois = object_boxes(n, size, size, seed=1)
-    masks, _ = saliency_masks(n, size, size, seed=0, attacked_fraction=0.15,
-                              boxes=rois)
+    rois = object_boxes(n, size, size, seed=seed + 1)
+    masks, _ = saliency_masks(n, size, size, seed=seed,
+                              attacked_fraction=0.15, boxes=rois)
     meta = np.zeros(n, MASK_META_DTYPE)
     meta["mask_id"] = np.arange(n)
     meta["image_id"] = np.arange(n) // 2
     meta["mask_type"] = np.arange(n) % 2 + 1
     cfg = CHIConfig(grid=16, num_bins=16, height=size, width=size)
-    return MaskStore.create_memory(masks, meta, cfg), rois
+    return masks, meta, rois, cfg
+
+
+def _synthetic_store(n: int, size: int):
+    """An in-memory store over :func:`synthetic_db`, its CHI built through
+    the ingest path (the ``chi_build`` kernel on the chip)."""
+    from ..core import MaskStore
+    from ..core.chi import build_chi_delta
+    masks, meta, rois, cfg = synthetic_db(n, size)
+    return MaskStore.create_memory(masks, meta, cfg,
+                                   chi_table=build_chi_delta(masks, cfg)), rois
 
 
 def main(argv=None):
@@ -312,6 +326,8 @@ def main(argv=None):
                          "its query regardless")
     ap.add_argument("--verbose", action="store_true")
     args = ap.parse_args(argv)
+    from ..compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     if args.root:
         from ..core import MaskStore

@@ -29,7 +29,8 @@ import numpy as np, jax, jax.numpy as jnp
 from repro.core import chi, cp, distributed as dist
 from repro.data.masks import saliency_masks
 
-mesh = dist.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 N, H, W = 64, 64, 64
 cfg = chi.CHIConfig(grid=8, num_bins=8, height=H, width=W)
 masks = saliency_masks(N, H, W, seed=3)[0]
@@ -66,7 +67,6 @@ def test_mesh_backend_multi_device_matches_host():
 import numpy as np, jax
 from repro.core import CHIConfig, MaskStore
 from repro.core.backend import MeshBackend
-from repro.core.distributed import make_mesh
 from repro.core.exprs import AggCP, BinOp, Cmp, CP, RoiArea
 from repro.core.plan import LogicalPlan, run_plan
 from repro.core.store import MASK_META_DTYPE
@@ -81,7 +81,8 @@ meta["image_id"] = np.arange(B) // 2
 meta["mask_type"] = np.arange(B) % 2 + 1
 cfg = CHIConfig(grid=8, num_bins=8, height=H, width=W)
 store = MaskStore.create_memory(masks, meta, cfg)
-be = MeshBackend(store, make_mesh((8,), ("data",)))
+be = MeshBackend(store, jax.make_mesh((8,), ("data",),
+                 axis_types=(jax.sharding.AxisType.Auto,)))
 
 plans = [
     LogicalPlan(predicate=Cmp(CP(None, 0.5, 1.0), ">", 500.0)),

@@ -386,3 +386,17 @@ def test_random_filtered_topk_plans_match_baseline(small_db):
                                       use_index=False)
         assert list(ids) == list(ids0), f"trial {trial}"
         np.testing.assert_allclose(scores, scores0, err_msg=f"trial {trial}")
+
+
+def test_division_bounds_contain_zero_denominator_quotient():
+    """x / 0 evaluates to 0, so a denominator whose lower bound is 0 must
+    leave the quotient's lower bound at 0 — else the index prunes a mask
+    whose exact score is 0 (a 0/0 pair ratio) from an ascending ranking."""
+    from repro.core.exprs import _exact_binop, _interval_binop
+    llb, lub = np.array([5.0, 0.0, 5.0]), np.array([10.0, 0.0, 10.0])
+    rlb, rub = np.array([0.0, 0.0, 2.0]), np.array([4.0, 3.0, 4.0])
+    lb, ub = _interval_binop("/", llb, lub, rlb, rub)
+    np.testing.assert_array_equal(lb, [0.0, 0.0, 1.25])
+    np.testing.assert_array_equal(ub, [np.inf, 0.0, 5.0])
+    exact = _exact_binop("/", np.array([7.0]), np.array([0.0]))
+    assert lb[0] <= exact[0] <= ub[0]
