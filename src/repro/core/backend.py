@@ -52,6 +52,7 @@ frontier comparison happens at full host precision everywhere.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import jax
@@ -59,6 +60,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..kernels import ops as kops
+from ..obs import trace as _trace
 from ..obs.metrics import REGISTRY as _REG
 from .chi import gather_rows
 from .distributed import (_bounds_from_corners, device_resolve,
@@ -440,15 +442,33 @@ class _KthValueMixin:
         return possible & (lb <= -tau)
 
 
+@dataclasses.dataclass
+class BackendStats:
+    """Host↔device traffic of the device backend (monotonic, always on;
+    ``/metrics`` as ``masksearch_backend_*``): bytes of step arguments
+    converted host→device, bytes fetched device→host, calls into jitted
+    steps or eager device ops, and output arrays fetched."""
+    h2d_bytes: int = 0
+    d2h_bytes: int = 0
+    device_calls: int = 0
+    fetches: int = 0
+
+
 class DeviceBackend(_KthValueMixin, ExecBackend):
     """Mask bytes + CHI table pinned in device memory; bounds and
-    verification jit-compiled over the Pallas kernels."""
+    verification jit-compiled over the Pallas kernels.
+
+    Every call into the device goes through :meth:`_step`, which counts
+    the host↔device traffic (:class:`BackendStats`) and, with tracing on,
+    splits the call into ``device.call`` / ``device.wait`` /
+    ``device.fetch`` spans."""
 
     name = "device"
 
     def __init__(self, store):
         self.store = store
         self.cfg = store.cfg
+        self.stats = BackendStats()
         self._packed = is_packed(store)   # resident array is uint32 words
         self._masks = store.device_masks()
         self._tables = store.chi_table
@@ -469,6 +489,60 @@ class DeviceBackend(_KthValueMixin, ExecBackend):
         self._epoch = self.store.epoch
         _BACKEND_SYNCS.labels(backend=self.name).inc()
 
+    # -- the one door to the device -----------------------------------------
+    def _put(self, a):
+        """A step argument on the device: resident arrays pass through,
+        host values are converted (their device bytes counted)."""
+        if isinstance(a, jax.Array):
+            return a
+        a = jnp.asarray(a)
+        self.stats.h2d_bytes += a.nbytes
+        return a
+
+    def _fetch(self, out):
+        """Device outputs (one array or a tuple) → numpy, counted."""
+        if isinstance(out, tuple):
+            return tuple(self._fetch(o) for o in out)
+        host = np.asarray(out)
+        self.stats.fetches += 1
+        self.stats.d2h_bytes += host.nbytes
+        return host
+
+    def _step(self, step: str, fn, *args, pick: int | None = None,
+              fetch: bool = True, **static):
+        """One call into the device: host ``args`` converted, ``fn``
+        dispatched (``static`` keywords passed through), output ``pick``
+        of a tuple chosen on the device, and the result fetched to the
+        host.  ``fetch=False`` returns the outputs on the device.
+
+        Traced, the call is three spans with attr ``step``:
+        ``device.call`` (conversion and the asynchronous dispatch),
+        ``device.wait`` (``block_until_ready``) and ``device.fetch`` (the
+        copy to the host).  Untraced, nothing waits but the fetch."""
+        self.stats.device_calls += 1
+        traced = _trace.current_tracer().enabled
+        with _trace.span("device.call") as sp:
+            sp.set(step=step)
+            out = fn(*[self._put(a) for a in args], **static)
+            if pick is not None:
+                out = out[pick]
+            if traced and fetch:
+                # Queue the copy to the host behind the step now, as a bare
+                # fetch does: queued after the wait, it would start only
+                # once the host had seen the step finish.
+                for o in out if isinstance(out, tuple) else (out,):
+                    o.copy_to_host_async()
+        if not fetch:
+            return out
+        if traced:
+            with _trace.span("device.wait") as sp:
+                sp.set(step=step)
+                jax.block_until_ready(out)
+        with _trace.span("device.fetch") as sp:
+            sp.set(step=step)
+            return self._fetch(out)
+
+    # -- primitives ------------------------------------------------------------
     def bounds(self, ctx, expr):
         if hasattr(ctx, "pair_rois"):
             return ctx.bounds(expr, pair_leaf=self._pair_cells)
@@ -495,49 +569,51 @@ class DeviceBackend(_KthValueMixin, ExecBackend):
             tables = self.store.chi_tier_table(g)
             rb, cb = self._tier_bounds(g)
         ks = value_ks(cfg, node.lv, node.uv)
-        lb, ub = _device_cp_bounds(
-            tables, jnp.asarray(mctx.positions),
-            jnp.asarray(rois, jnp.int32), rb, cb,
-            jnp.asarray(ks))
-        return np.asarray(lb, np.float64), np.asarray(ub, np.float64)
+        lb, ub = self._step(
+            "_device_cp_bounds", _device_cp_bounds, tables,
+            np.asarray(mctx.positions), np.asarray(rois, np.int32), rb, cb,
+            np.asarray(ks))
+        return lb.astype(np.float64), ub.astype(np.float64)
 
     def _pair_cells(self, pctx, node):
         rois = pctx.pair_rois(node.roi)
         ka = _threshold_ks(self.cfg, node.ta)
         kb = _threshold_ks(self.cfg, node.tb)
-        lb, ub = _device_pair_cells(
-            self._tables, jnp.asarray(pctx.pos_a), jnp.asarray(pctx.pos_b),
-            jnp.asarray(np.array([ka[0], ka[1], kb[0], kb[1]], np.int32)),
-            jnp.asarray(rois, jnp.int32), self._rb, self._cb,
-            stat=node.stat)
-        return np.asarray(lb, np.float64), np.asarray(ub, np.float64)
+        lb, ub = self._step(
+            "_device_pair_cells", _device_pair_cells, self._tables,
+            np.asarray(pctx.pos_a), np.asarray(pctx.pos_b),
+            np.array([ka[0], ka[1], kb[0], kb[1]], np.int32),
+            np.asarray(rois, np.int32), self._rb, self._cb, stat=node.stat)
+        return lb.astype(np.float64), ub.astype(np.float64)
+
+    def _multi(self):
+        """The fused CP-count step for the resident representation."""
+        if self._packed:
+            return "_device_multi_counts_packed", _device_multi_counts_packed
+        return "_device_multi_counts", _device_multi_counts
 
     def verify_counts(self, ctx, batch, terms):
         terms = list(terms)
         pos = ctx.positions[batch]
         rois_q, lvs, uvs = spec_arrays(
             [(ctx.resolve_rois(t.roi, pos), t.lv, t.uv) for t in terms])
-        multi = (_device_multi_counts_packed if self._packed
-                 else _device_multi_counts)
-        counts = np.asarray(multi(
-            self._masks, jnp.asarray(pos), jnp.asarray(rois_q),
-            jnp.asarray(lvs), jnp.asarray(uvs)))
+        counts = self._step(*self._multi(), self._masks, np.asarray(pos),
+                            rois_q, lvs, uvs)
         return {t: counts[i].astype(np.float64)
                 for i, t in enumerate(terms)}
 
     def _fused_verify_batch(self, ctx, batch, pos, rois_q, lvs, uvs,
                             decided, lb):
-        return np.asarray(_device_fused_verify(
-            self._masks, jnp.asarray(np.asarray(pos)), jnp.asarray(rois_q),
-            jnp.asarray(lvs), jnp.asarray(uvs), jnp.asarray(decided),
-            jnp.asarray(lb)))
+        return self._step("_device_fused_verify", _device_fused_verify,
+                          self._masks, np.asarray(pos), rois_q, lvs, uvs,
+                          decided, lb)
 
     def topk_candidates(self, lb, ub, k, desc, definite, possible):
         if k <= 0 or int(np.count_nonzero(definite)) < k:
             return possible.copy()
         pes32 = (lb if desc else -ub).astype(np.float32)
-        tau_idx = int(_device_kth_index(jnp.asarray(pes32),
-                                        jnp.asarray(definite), k))
+        tau_idx = int(self._step("_device_kth_index", _device_kth_index,
+                                 pes32, np.asarray(definite), k=k))
         return self._alive_from_index(lb, ub, k, desc, definite, possible,
                                       pes32, tau_idx)
 
@@ -545,42 +621,50 @@ class DeviceBackend(_KthValueMixin, ExecBackend):
         gidx = np.asarray(gidx)
         s = gctx.groups.shape[1]
         flat = gctx.groups[gidx].reshape(-1)
-        rois = gctx.resolve_group_rois(node.roi, gidx)
+        rois = np.asarray(gctx.resolve_group_rois(node.roi, gidx), np.int32)
+        pick = 0 if node.agg == "intersect" else 1     # (inter, union)
         if self._packed:
-            inter, union = _device_group_counts_packed(
-                self._masks, jnp.asarray(flat), jnp.asarray(rois, jnp.int32),
-                jnp.asarray(node.thresh, jnp.float32), s=int(s))
+            counts = self._step(
+                "_device_group_counts_packed", _device_group_counts_packed,
+                self._masks, flat, rois, np.asarray(node.thresh, np.float32),
+                pick=pick, s=int(s))
         else:
-            inter, union = _device_group_counts(
-                self._masks, jnp.asarray(flat), jnp.asarray(rois, jnp.int32),
-                jnp.asarray(node.thresh, self._masks.dtype), s=int(s))
-        counts = inter if node.agg == "intersect" else union
-        return np.asarray(counts, np.float64)
+            counts = self._step(
+                "_device_group_counts", _device_group_counts, self._masks,
+                flat, rois, np.asarray(node.thresh, self._masks.dtype),
+                pick=pick, s=int(s))
+        return counts.astype(np.float64)
 
     def fused_counts(self, store, positions, specs):
         rois_q, lvs, uvs = spec_arrays(specs)
-        multi = (_device_multi_counts_packed if self._packed
-                 else _device_multi_counts)
-        return np.asarray(multi(
-            self._masks, jnp.asarray(np.asarray(positions)),
-            jnp.asarray(rois_q), jnp.asarray(lvs), jnp.asarray(uvs)))
+        return self._step(*self._multi(), self._masks,
+                          np.asarray(positions), rois_q, lvs, uvs)
 
     def fused_pair_counts(self, store, pos_a, pos_b, specs):
         # Both roles are resident (the store's one HBM mask array); gather
         # each role ONCE and answer every descriptor against the gathered
         # batch — zero metered bytes, 2 gathers regardless of Q.
-        a = self._masks[jnp.asarray(np.asarray(pos_a))]
-        b = self._masks[jnp.asarray(np.asarray(pos_b))]
-        kernel = kops.pair_counts_packed if self._packed else kops.pair_counts
-        tdt = jnp.float32 if self._packed else a.dtype
+        a = self._step("gather", _gather, self._masks, np.asarray(pos_a),
+                       fetch=False)
+        b = self._step("gather", _gather, self._masks, np.asarray(pos_b),
+                       fetch=False)
+        if self._packed:
+            step, kernel, tdt = ("pair_counts_packed",
+                                 kops.pair_counts_packed, np.float32)
+        else:
+            step, kernel, tdt = "pair_counts", kops.pair_counts, a.dtype
         out = np.empty((len(specs), 3, len(pos_a)), np.int64)
         for qi, (rois, ta, tb) in enumerate(specs):
-            trio = kernel(
-                a, b, jnp.asarray(np.asarray(rois), jnp.int32),
-                jnp.asarray(ta, tdt), jnp.asarray(tb, tdt))
+            trio = self._step(step, kernel, a, b, np.asarray(rois, np.int32),
+                              np.asarray(ta, tdt), np.asarray(tb, tdt))
             for row, counts in enumerate(trio):
-                out[qi, row] = np.asarray(counts)
+                out[qi, row] = counts
         return out
+
+
+def _gather(masks, pos):
+    """Rows of the resident array (an eager device gather)."""
+    return masks[pos]
 
 
 # ---------------------------------------------------------------------------
@@ -834,5 +918,5 @@ def get_backend(store, backend=None) -> ExecBackend:
 
 
 __all__ = ["ExecBackend", "HostBackend", "DeviceBackend", "MeshBackend",
-           "F32_MAX", "chi_verdicts", "get_backend", "host_backend",
+           "BackendStats", "F32_MAX", "chi_verdicts", "get_backend", "host_backend",
            "is_packed", "spec_arrays"]

@@ -21,12 +21,12 @@ from __future__ import annotations
 
 import functools
 import os
-import time
 
 import jax
 import jax.numpy as jnp
 
 from ..obs.metrics import REGISTRY as _REG
+from ..obs.metrics import watch_compiles
 from . import popcount, ref
 from .chi_build import chi_cell_hist_pallas
 from .cp_count import cp_count_multi_pallas, cp_count_pallas
@@ -37,36 +37,24 @@ _FORCE_INTERPRET = os.environ.get("REPRO_FORCE_PALLAS_INTERPRET", "") == "1"
 
 _KERNEL_LAUNCHES = _REG.counter(
     "masksearch_kernel_launches_total",
-    "Dispatches through each public kernel wrapper", ("kernel",))
-_KERNEL_SECONDS = _REG.histogram(
-    "masksearch_kernel_dispatch_seconds",
-    "Wall time per kernel wrapper dispatch (first call includes trace+jit "
-    "compile; steady-state is the launch itself)", ("kernel",))
-_JIT_COMPILES = _REG.counter(
-    "masksearch_jit_compiles_total",
-    "jit cache-entry growth observed per wrapper — a steadily rising count "
-    "means shape/static-arg churn is defeating the jit cache", ("kernel",))
+    "Calls through each public kernel wrapper (inside a jitted device "
+    "step a call happens when the step is traced, not per execution)",
+    ("kernel",))
+
+# Programs XLA builds, per jitted function: masksearch_compiles_total.
+watch_compiles()
 
 
 def _instrument(name: str, fn):
-    """Wrap a jitted kernel entry point with launch counting, dispatch
-    timing, and recompile detection (via the jit cache-size delta)."""
+    """Wrap a jitted kernel entry point with launch counting."""
     launches = _KERNEL_LAUNCHES.labels(kernel=name)
-    seconds = _KERNEL_SECONDS.labels(kernel=name)
-    compiles = _JIT_COMPILES.labels(kernel=name)
 
     @functools.wraps(fn)
     def wrapper(*args, **kw):
-        before = fn._cache_size()
-        t0 = time.perf_counter()
         try:
             return fn(*args, **kw)
         finally:
-            seconds.observe(time.perf_counter() - t0)
             launches.inc()
-            after = fn._cache_size()
-            if before < after:
-                compiles.inc(after - before)
 
     return wrapper
 

@@ -5,8 +5,9 @@ One registry absorbs every counter the system already keeps — the engine's
 the scheduler's ``SchedulerStats``, the planner's ``CacheInfo`` — plus the
 new first-class instruments: query/phase latency **histograms** (fixed
 log-spaced buckets; p50/p95/p99 derivable at read time), per-kernel launch
-counters + dispatch timing, and jit-recompile counters
-(:mod:`repro.kernels.ops`).
+counters (:mod:`repro.kernels.ops`), the programs XLA built per jitted
+function (:func:`watch_compiles`), and the span tracer's running totals
+(:func:`span_sampler`).
 
 Pull-based: live stats objects are wired in as *collectors* (callables
 sampled at scrape time), so ``/metrics`` always reflects current state
@@ -32,7 +33,8 @@ from typing import Callable, Optional, Sequence
 from .. import lockcheck
 
 __all__ = ["MetricsRegistry", "REGISTRY", "get_registry",
-           "DEFAULT_TIME_BUCKETS", "dataclass_sampler"]
+           "DEFAULT_TIME_BUCKETS", "dataclass_sampler", "span_sampler",
+           "watch_compiles"]
 
 #: Log-spaced latency buckets, 100 µs … 10 s (upper bounds, seconds).
 DEFAULT_TIME_BUCKETS = (
@@ -313,9 +315,66 @@ def dataclass_sampler(name_prefix: str, mtype: str, help: str,
     return collect
 
 
+def span_sampler(totals: Callable[[], dict]) -> Callable[[], list]:
+    """Build a collector over a tracer's running totals
+    (:meth:`repro.obs.trace.Tracer.totals`): finished spans, their summed
+    seconds and summed self seconds, per span name — the numbers a
+    benchmark window reads as differences."""
+
+    def collect() -> list:
+        tot = totals()
+        return [
+            ("masksearch_spans_total", "counter", "Finished spans by name",
+             [({"span": n}, float(t["count"])) for n, t in tot.items()]),
+            ("masksearch_span_seconds_total", "counter",
+             "Summed span durations by name",
+             [({"span": n}, t["seconds"]) for n, t in tot.items()]),
+            ("masksearch_span_self_seconds_total", "counter",
+             "Summed span self times (duration minus children) by name",
+             [({"span": n}, t["self_seconds"]) for n, t in tot.items()]),
+        ]
+
+    return collect
+
+
 REGISTRY = MetricsRegistry()
+
+#: The event JAX records once per program it builds (compiled, or loaded
+#: from the persistent compile cache), with the jitted function's name.
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+_COMPILES = REGISTRY.counter(
+    "masksearch_compiles_total",
+    "Programs XLA built (compiled or loaded from the persistent cache) "
+    "per jitted function", ("fn",))
+_COMPILE_SECONDS = REGISTRY.counter(
+    "masksearch_compile_seconds_total",
+    "Seconds spent building those programs per jitted function", ("fn",))
+_watching = False
+_WATCH_LOCK = lockcheck.make_lock("metrics.watch")
+
+
+def watch_compiles() -> None:
+    """Feed ``masksearch_compiles_total{fn}`` and
+    ``masksearch_compile_seconds_total{fn}`` from JAX's compile event.
+    Registers one ``jax.monitoring`` listener per process (JAX keeps its
+    listeners for the life of the process); later calls do nothing."""
+    global _watching
+    with _WATCH_LOCK:
+        if _watching:
+            return
+        _watching = True
+    import jax
+
+    def on_event(event, duration, fun_name="?", **_):
+        if event == COMPILE_EVENT:
+            _COMPILES.labels(fn=fun_name).inc()
+            _COMPILE_SECONDS.labels(fn=fun_name).inc(duration)
+
+    jax.monitoring.register_event_duration_secs_listener(on_event)
 
 
 def get_registry() -> MetricsRegistry:
-    """The process-global registry (kernel launch/jit counters live here)."""
+    """The process-global registry (kernel launches, compiles, backend
+    counters live here)."""
     return REGISTRY

@@ -5,10 +5,13 @@ filter–verification pipeline: ``query`` → ``parse`` → ``plan.compile`` →
 per-expression ``bounds`` spans (candidates, CHI bytes touched) →
 ``verify.round`` spans (masks, bytes, cache hits) — plus
 ``scheduler.fused_pass`` / ``scheduler.pair_pass`` when the service's
-cross-query scheduler drives verification.  The span *structure* (names,
-nesting, candidate/verified counts) is identical across the host, device,
-and mesh backends because instrumentation lives in the backend-agnostic
-drivers, never in the physical layers.
+cross-query scheduler drives verification.  The served path adds the
+async tier's ``tier.*`` spans, the service's ``service.*`` spans and the
+scheduler's ``scheduler.drive`` / ``scheduler.round`` (DESIGN.md §10).
+The span *structure* (names, nesting, candidate/verified counts) is
+identical across the host, device, and mesh backends: instrumentation
+lives in the backend-agnostic drivers, and the device backend's own
+``device.*`` spans are left out of :meth:`Span.structure`.
 
 Design constraints:
 
@@ -23,6 +26,13 @@ Design constraints:
 * **Exportable.**  A finished trace renders as nested JSON
   (:meth:`Span.to_dict`) or as the Chrome trace-event format
   (:func:`chrome_trace` — load the JSON file in Perfetto / chrome://tracing).
+* **On the profiler's clock.**  While a tracer is enabled every span also
+  holds a ``jax.profiler.TraceAnnotation`` of its name, so a JAX profiler
+  trace shows the program's spans on the host plane beside the device's
+  ``XLA Ops``.
+* **Window totals.**  Every finished span adds its count, duration and
+  self time (duration minus its children's) to per-name running totals
+  (:meth:`Tracer.totals`), which ``/metrics`` exports.
 """
 
 from __future__ import annotations
@@ -44,6 +54,8 @@ def _jsonable(v):
     """Attrs may carry numpy scalars; normalize for json.dumps."""
     if isinstance(v, bool) or v is None or isinstance(v, (str, int, float)):
         return v
+    if isinstance(v, (list, tuple)):
+        return [_jsonable(x) for x in v]
     if hasattr(v, "item"):
         return v.item()
     return repr(v)
@@ -53,17 +65,20 @@ class Span:
     """One timed node of a trace tree.  Use as a context manager; annotate
     with :meth:`set` (attrs merge; later wins)."""
 
-    __slots__ = ("name", "t0", "dur_s", "attrs", "children",
-                 "_tracer", "_token")
+    __slots__ = ("name", "t0", "dur_s", "child_s", "attrs", "children",
+                 "_tracer", "_token", "_keep", "_note")
 
-    def __init__(self, name: str, tracer: "Tracer"):
+    def __init__(self, name: str, tracer: "Tracer", keep: bool = True):
         self.name = name
         self.t0 = 0.0
         self.dur_s = 0.0
+        self.child_s = 0.0               # summed durations of the children
         self.attrs: dict = {}
         self.children: list = []
         self._tracer = tracer
         self._token = None
+        self._keep = keep                # a finished root enters the ring
+        self._note = None                # the profiler annotation
 
     def set(self, **attrs) -> "Span":
         self.attrs.update(attrs)
@@ -71,20 +86,30 @@ class Span:
 
     # -- context management ----------------------------------------------
     def __enter__(self) -> "Span":
-        self.t0 = time.perf_counter()
+        self._note = _annotation(self.name)
+        self._note.__enter__()
+        self.t0 = _now()
         self._token = _CURRENT_SPAN.set(self)
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
-        self.dur_s = time.perf_counter() - self.t0
+        self.dur_s = _now() - self.t0
+        self._note.__exit__(None, None, None)
+        self._note = None
         _CURRENT_SPAN.reset(self._token)
         self._token = None
         if exc_type is not None:
             self.attrs.setdefault("error", exc_type.__name__)
-        if _CURRENT_SPAN.get() is None:
-            # finished root: record into the owning tracer's ring buffer
-            self._tracer._record(self)
+        parent = _CURRENT_SPAN.get()
+        if parent is not None:
+            parent.child_s += self.dur_s
+        self._tracer._finish(self, root=parent is None)
         return False
+
+    @property
+    def self_s(self) -> float:
+        """Duration not covered by the children (choosing-metrics §4)."""
+        return self.dur_s - self.child_s
 
     # -- export -----------------------------------------------------------
     def to_dict(self) -> dict:
@@ -104,12 +129,17 @@ class Span:
     def structure(self) -> tuple:
         """The backend-invariant shape of the subtree: span names, nesting,
         and the count-valued attrs (times/bytes excluded — those may differ
-        across physical backends; counts must not)."""
+        across physical backends; counts must not).  ``device.*`` spans are
+        the physical layer and are left out with their subtrees."""
         counts = {k: _jsonable(v) for k, v in self.attrs.items()
                   if k in _STRUCTURAL_ATTRS}
         return (self.name, tuple(sorted(counts.items())),
-                tuple(c.structure() for c in self.children))
+                tuple(c.structure() for c in self.children
+                      if not c.name.startswith(PHYSICAL_PREFIX)))
 
+
+#: Spans of the physical layer, outside the backend-invariant structure.
+PHYSICAL_PREFIX = "device."
 
 #: Attr names that must be bit-identical across execution backends.
 _STRUCTURAL_ATTRS = frozenset({
@@ -136,6 +166,23 @@ class _NoopSpan:
 
 NOOP_SPAN = _NoopSpan()
 
+#: The spans' clock; :meth:`Tracer.record` takes stamps of the same clock.
+_now = time.perf_counter
+
+_TRACE_ANNOTATION = None
+
+
+def _annotation(name: str):
+    """A ``jax.profiler.TraceAnnotation`` for ``name``: the span on the
+    profiler's host plane.  JAX is imported on the first enabled span, so
+    importing this module (and the disabled path) never loads it."""
+    global _TRACE_ANNOTATION
+    if _TRACE_ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+        _TRACE_ANNOTATION = TraceAnnotation
+    return _TRACE_ANNOTATION(name)
+
+
 _CURRENT_SPAN: contextvars.ContextVar[Optional[Span]] = \
     contextvars.ContextVar("repro_obs_current_span", default=None)
 _ACTIVE_TRACER: contextvars.ContextVar[Optional["Tracer"]] = \
@@ -155,21 +202,48 @@ class Tracer:
         self.max_traces = max_traces
         self.spans_started = 0           # the zero-allocation check counter
         self._traces: OrderedDict[str, Span] = OrderedDict()
+        # span name -> [count, seconds, self seconds], every finished span
+        self._totals: dict = {}
         self._ids = itertools.count(1)
         self._lock = lockcheck.make_lock("obs.tracer")
 
     # -- span creation -----------------------------------------------------
-    def span(self, name: str):
-        """Start a child span of the current context (or a new root)."""
+    def span(self, name: str, *, keep: bool = True):
+        """Start a child span of the current context (or a new root).  A
+        root opened with ``keep=False`` feeds the totals but not the
+        finished-trace ring (the async tier's per-request fragments, which
+        are not a query's tree)."""
         if not self.enabled:
             return NOOP_SPAN
         with self._lock:
             self.spans_started += 1
-        sp = Span(name, self)
+        sp = Span(name, self, keep)
         parent = _CURRENT_SPAN.get()
         if parent is not None:
             parent.children.append(sp)
         return sp
+
+    def record(self, name: str, t0: float, t1: float, **attrs) -> None:
+        """Record an interval timed elsewhere — one that starts on one
+        thread and ends on another, like a request's wait in the tier's
+        queue.  ``t0``/``t1`` are ``time.perf_counter()`` stamps.  It feeds
+        the totals and, as a child, the current span's tree (counted in the
+        parent's child time when it lies inside the parent), but not the
+        profiler, which only sees intervals entered and left on one
+        thread."""
+        if not self.enabled:
+            return
+        with self._lock:
+            self.spans_started += 1
+        sp = Span(name, self, keep=False)
+        sp.t0, sp.dur_s = t0, max(t1 - t0, 0.0)
+        sp.attrs.update(attrs)
+        parent = _CURRENT_SPAN.get()
+        if parent is not None:
+            parent.children.append(sp)
+            if t0 >= parent.t0:
+                parent.child_s += sp.dur_s
+        self._finish(sp, root=False)
 
     def query_span(self, label: str = "", query_id: Optional[str] = None):
         """Start a root ``query`` span with an assigned ``query_id`` attr
@@ -196,7 +270,26 @@ class Tracer:
         finally:
             _ACTIVE_TRACER.reset(token)
 
-    # -- finished-trace retention -----------------------------------------
+    # -- totals and finished-trace retention -------------------------------
+    def _finish(self, sp: Span, *, root: bool) -> None:
+        with self._lock:
+            tot = self._totals.get(sp.name)
+            if tot is None:
+                tot = self._totals[sp.name] = [0, 0.0, 0.0]
+            tot[0] += 1
+            tot[1] += sp.dur_s
+            tot[2] += sp.self_s
+        if root and sp._keep:
+            self._record(sp)
+
+    def totals(self) -> dict:
+        """Running totals per span name since the tracer was built:
+        ``{name: {"count", "seconds", "self_seconds"}}``.  Two readings
+        bracket a window; their difference is the window's."""
+        with self._lock:
+            return {name: {"count": c, "seconds": s, "self_seconds": own}
+                    for name, (c, s, own) in self._totals.items()}
+
     def _record(self, root: Span) -> None:
         qid = root.attrs.get("query_id")
         if qid is None:

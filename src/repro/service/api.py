@@ -39,7 +39,7 @@ from ..core.store import MASK_META_DTYPE, StaleRunError
 from ..obs import trace as trace_mod
 from ..obs.explain import explain_analyze, explain_plan
 from ..obs.metrics import REGISTRY as GLOBAL_REGISTRY
-from ..obs.metrics import MetricsRegistry, dataclass_sampler
+from ..obs.metrics import MetricsRegistry, dataclass_sampler, span_sampler
 from .errors import NotFoundError
 from .planner import Planner, roi_signature
 from .scheduler import FusedScheduler
@@ -53,6 +53,14 @@ def _stats_dict(stats: ExecStats) -> dict:
     d["load_fraction"] = stats.load_fraction
     return {k: float(v) if isinstance(v, float) else int(v)
             for k, v in d.items()}
+
+
+def _plan_s(build_s: float, run) -> float:
+    """The lowering cost inside a timed build: ``build_s`` wraps compile
+    (and, in :meth:`MaskSearchService.query`, the drive); carve out the
+    metered bounds and verify time the run has spent so far."""
+    s = run.stats
+    return max(build_s - s.bound_time_s - s.verify_time_s, 0.0)
 
 
 def _ids_list(ids) -> list:
@@ -136,6 +144,12 @@ class MaskSearchService:
             "masksearch_scheduler", "counter",
             "Fused cross-query verification scheduler",
             lambda: self.scheduler.stats))
+        if hasattr(self.backend, "stats"):      # the device backend
+            reg.register_collector(dataclass_sampler(
+                "masksearch_backend", "counter",
+                "Host-device traffic of the device backend",
+                lambda: self.backend.stats))
+        reg.register_collector(span_sampler(self.tracer.totals))
         self.planner.register_metrics(reg)
 
         def _query_counts() -> list:
@@ -182,18 +196,15 @@ class MaskSearchService:
                 root.set(kind=kind)
                 yield root
 
-    def _observe_phases(self, parse_s: float, build_s: float, run,
+    def _observe_phases(self, parse_s: float, plan_s: float, run,
                         kind: str, total_s: float) -> None:
+        """``plan_s`` is the pure lowering cost (see :func:`_plan_s`); the
+        bounds and verify phases come from the run's ``ExecStats``."""
         ph = self._phase_hist
         ph.labels(phase="parse").observe(parse_s)
-        if run is None:                      # result-cache hit: no run
-            ph.labels(phase="plan").observe(build_s)
-        else:
+        ph.labels(phase="plan").observe(plan_s)
+        if run is not None:                  # None: a result-cache hit
             s = run.stats
-            # build_s wraps compile+ensure; carve out the metered bounds
-            # and verify time so "plan" is the pure lowering cost.
-            ph.labels(phase="plan").observe(
-                max(build_s - s.bound_time_s - s.verify_time_s, 0.0))
             ph.labels(phase="bounds").observe(s.bound_time_s)
             ph.labels(phase="verify").observe(s.verify_time_s)
         self._query_seconds.labels(kind=kind).observe(total_s)
@@ -320,8 +331,8 @@ class MaskSearchService:
                     payload = self._serve_page(sess, size)
                 if root is not None:
                     payload["query_id"] = root.attrs.get("query_id")
-                self._observe_phases(parse_s, build_s, run, plan.kind,
-                                     time.perf_counter() - t_start)
+                self._observe_phases(parse_s, _plan_s(build_s, run), run,
+                                     plan.kind, time.perf_counter() - t_start)
                 return payload
 
             cached = self.planner.cached_result(plan, roi_sig,
@@ -345,8 +356,8 @@ class MaskSearchService:
             self.planner.store_result(plan, roi_sig, copy.deepcopy(payload),
                                       self.backend.name, self.store.epoch,
                                       packed=self._packed)
-            self._observe_phases(parse_s, build_s, run, plan.kind,
-                                 time.perf_counter() - t_start)
+            self._observe_phases(parse_s, _plan_s(build_s, run), run,
+                                 plan.kind, time.perf_counter() - t_start)
             return payload
 
     def submit_batch(self, sqls: Sequence, *, rois=None) -> list:
@@ -405,101 +416,150 @@ class MaskSearchService:
         Each item is a dict::
 
             {"op": "query", "sql": ..., "rois"?, "session"?: bool,
-             "page_size"?, "tenant"?}
-            {"op": "page", "session_id": ..., "k"?, "tenant"?}
+             "page_size"?, "tenant"?, "rid"?}
+            {"op": "page", "session_id": ..., "k"?, "tenant"?, "rid"?}
+
+        ``rid`` is the tier's request id, carried into the spans.  With
+        the tracer on the whole call is traced: ``service.execute`` (one per
+        batch) over a ``service.item`` per item (planning, bounds, result
+        cache) and a ``service.finish`` per item (result, payload, cache
+        store), with the scheduler's drive between them.  Every item feeds
+        the phase histograms, as :meth:`query` does.
 
         Returns a list aligned with ``items`` of ``("ok", payload)`` /
         ``("error", exc)`` — a bad item never poisons its batchmates.
         """
-        with self._lock:
+        tr = self.tracer
+        scope = tr.activate() if tr.enabled else contextlib.nullcontext()
+        t_start = time.perf_counter()
+        with scope, trace_mod.span("service.execute") as top, self._lock:
+            if tr.enabled:
+                top.set(items=len(items),
+                        rids=[it.get("rid") for it in items])
             results: list = [None] * len(items)
-            pending: list = []            # (slot, tag, *state) to finish
+            pending: list = []            # (slot, tag, timing, *state)
             runs: list = []
             tenants: list = []
 
             for slot, item in enumerate(items):
-                try:
-                    tenant = item.get("tenant", "default")
-                    if item.get("op", "query") == "page":
-                        sess = self.sessions.get(item["session_id"])
-                        k = item.get("k")
-                        if not sess.done:
-                            _, hi = sess.page_bounds(k)
-                            sess.run.target(hi)
-                            if not sess.run.resumable():
-                                raise StaleRunError(
-                                    f"session pinned at epoch "
-                                    f"{sess.run.epoch}; store moved to "
-                                    f"epoch {self.store.epoch}")
-                            runs.append(sess.run)
-                            tenants.append(tenant)
-                        pending.append((slot, "page", sess, k))
+                with trace_mod.span("service.item") as sp:
+                    sp.set(rid=item.get("rid"), cache_hit=False)
+                    try:
+                        entry = self._admit_item(slot, item, sp, t_start)
+                    except Exception as e:  # noqa: BLE001 — per-item fault
+                        results[slot] = ("error", e)
                         continue
-
-                    sql = item["sql"]
-                    rois, roi_sig = self._rois(item.get("rois"))
-                    plan, explain = self._plan_explain(sql)
-                    if explain is not None:
-                        results[slot] = ("ok", self._explain_payload(
-                            plan, explain, rois, roi_sig, sql))
-                        continue
-                    self._counts["total"] += 1
-                    self._counts[plan.kind] = \
-                        self._counts.get(plan.kind, 0) + 1
-                    if item.get("session"):
-                        if plan.kind not in ("topk", "filtered_topk"):
-                            raise ValueError(
-                                "sessions require a ranking (ORDER BY … "
-                                f"LIMIT) query, got {plan.kind!r}")
-                        size = item.get("page_size") or plan.k or DEFAULT_PAGE
-                        run = self._build_run(plan, rois, roi_sig)
-                        sess = self.sessions.create(
-                            sql if isinstance(sql, str) else repr(plan),
-                            run, size, kind=plan.kind)
-                        _, hi = sess.page_bounds(size)
-                        run.target(hi)
-                        runs.append(run)
-                        tenants.append(tenant)
-                        pending.append((slot, "open", sess, size))
-                        continue
-                    cached = self.planner.cached_result(
-                        plan, roi_sig, self.backend.name, self.store.epoch,
-                        packed=self._packed)
-                    if cached is not None:
-                        results[slot] = ("ok",
-                                         self._cache_hit_payload(cached))
-                        continue
-                    run = self._build_run(plan, rois, roi_sig)
-                    if plan.k is not None:
-                        run.target(plan.k)
-                    runs.append(run)
-                    tenants.append(tenant)
-                    pending.append((slot, "oneshot", plan, run, roi_sig))
-                except Exception as e:      # noqa: BLE001 — per-item fault
-                    results[slot] = ("error", e)
+                if entry[1] == "done":
+                    results[slot] = ("ok", entry[2])
+                    continue
+                pending.append(entry)
+                if entry[-1] is not None:
+                    runs.append(entry[-1])
+                    tenants.append(item.get("tenant", "default"))
 
             if runs:
-                with self._traced(f"admit[{len(runs)}]", "admitted_batch"):
-                    self.scheduler.drive(runs, tenants=tenants)
+                self.scheduler.drive(runs, tenants=tenants)
 
             for entry in pending:
-                slot, tag = entry[0], entry[1]
-                try:
-                    if tag == "oneshot":
-                        _, _, plan, run, roi_sig = entry
-                        payload = self._finish_payload(plan, run)
-                        self.planner.store_result(
-                            plan, roi_sig, copy.deepcopy(payload),
-                            self.backend.name, self.store.epoch,
-                            packed=self._packed)
-                    else:                   # "open" | "page"
-                        _, _, sess, k = entry
-                        payload = self._serve_page(sess, k,
-                                                   scheduler_driven=True)
-                    results[slot] = ("ok", payload)
-                except Exception as e:      # noqa: BLE001 — per-item fault
-                    results[slot] = ("error", e)
+                slot = entry[0]
+                with trace_mod.span("service.finish") as sp:
+                    sp.set(rid=items[slot].get("rid"))
+                    try:
+                        results[slot] = ("ok", self._finish_item(entry))
+                    except Exception as e:  # noqa: BLE001 — per-item fault
+                        results[slot] = ("error", e)
+                        continue
+                self._observe_item(entry, time.perf_counter() - t_start)
             return results
+
+    def _admit_item(self, slot: int, item: dict, sp, t_start: float) -> tuple:
+        """Plan one item of :meth:`execute_many` → ``(slot, "done",
+        payload)`` when it is answered already (EXPLAIN, a result-cache
+        hit), else ``(slot, tag, timing, state..., run_to_drive)``."""
+        if item.get("op", "query") == "page":
+            sp.set(kind="page")
+            sess = self.sessions.get(item["session_id"])
+            k = item.get("k")
+            run = None
+            if not sess.done:
+                _, hi = sess.page_bounds(k)
+                sess.run.target(hi)
+                if not sess.run.resumable():
+                    raise StaleRunError(
+                        f"session pinned at epoch {sess.run.epoch}; store "
+                        f"moved to epoch {self.store.epoch}")
+                run = sess.run
+            timing = (sess.run.stats.verify_time_s,)
+            return (slot, "page", timing, sess, k, run)
+
+        sql = item["sql"]
+        rois, roi_sig = self._rois(item.get("rois"))
+        t0 = time.perf_counter()
+        plan, explain = self._plan_explain(sql)
+        parse_s = time.perf_counter() - t0
+        sp.set(kind=plan.kind)
+        if explain is not None:
+            return (slot, "done", self._explain_payload(
+                plan, explain, rois, roi_sig, sql))
+        self._counts["total"] += 1
+        self._counts[plan.kind] = self._counts.get(plan.kind, 0) + 1
+        if item.get("session"):
+            if plan.kind not in ("topk", "filtered_topk"):
+                raise ValueError("sessions require a ranking (ORDER BY … "
+                                 f"LIMIT) query, got {plan.kind!r}")
+            size = item.get("page_size") or plan.k or DEFAULT_PAGE
+            t1 = time.perf_counter()
+            run = self._build_run(plan, rois, roi_sig)
+            timing = (parse_s, _plan_s(time.perf_counter() - t1, run),
+                      plan.kind)
+            sess = self.sessions.create(
+                sql if isinstance(sql, str) else repr(plan), run, size,
+                kind=plan.kind)
+            _, hi = sess.page_bounds(size)
+            run.target(hi)
+            return (slot, "open", timing, sess, size, run)
+        cached = self.planner.cached_result(
+            plan, roi_sig, self.backend.name, self.store.epoch,
+            packed=self._packed)
+        if cached is not None:
+            sp.set(cache_hit=True)
+            payload = self._cache_hit_payload(cached)
+            self._observe_phases(parse_s, 0.0, None, plan.kind,
+                                 time.perf_counter() - t_start)
+            return (slot, "done", payload)
+        t1 = time.perf_counter()
+        run = self._build_run(plan, rois, roi_sig)
+        if plan.k is not None:
+            run.target(plan.k)
+        timing = (parse_s, _plan_s(time.perf_counter() - t1, run), plan.kind)
+        return (slot, "oneshot", timing, plan, roi_sig, run)
+
+    def _finish_item(self, entry: tuple) -> dict:
+        """The payload of a driven item: its result, shaped, and for a
+        one-shot query a copy stored in the result cache."""
+        if entry[1] == "oneshot":
+            _, _, _, plan, roi_sig, run = entry
+            payload = self._finish_payload(plan, run)
+            self.planner.store_result(
+                plan, roi_sig, copy.deepcopy(payload), self.backend.name,
+                self.store.epoch, packed=self._packed)
+            return payload
+        _, _, _, sess, k, _ = entry              # "open" | "page"
+        return self._serve_page(sess, k, scheduler_driven=True)
+
+    def _observe_item(self, entry: tuple, total_s: float) -> None:
+        """Phase histograms for one finished item of :meth:`execute_many`:
+        a query's parse, plan, bounds and verify times (its run's
+        ``ExecStats``); a page's verification since the batch began."""
+        tag, timing = entry[1], entry[2]
+        if tag == "page":
+            run = entry[3].run
+            self._phase_hist.labels(phase="verify").observe(
+                run.stats.verify_time_s - timing[0])
+            self._query_seconds.labels(kind="page").observe(total_s)
+            return
+        parse_s, plan_s, kind = timing
+        self._observe_phases(parse_s, plan_s, entry[-1], kind, total_s)
 
     # -- sessions ---------------------------------------------------------
 
@@ -693,9 +753,10 @@ class MaskSearchService:
 
     def metrics_text(self) -> str:
         """The Prometheus text exposition ``GET /metrics`` serves: this
-        service's registry (queries, phases, store I/O, caches, sessions)
-        followed by the process-global registry (kernel launches, jit
-        compiles, backend resolutions)."""
+        service's registry (queries, phases, store I/O, caches, sessions,
+        span totals, the device backend's transfers) followed by the
+        process-global registry (kernel launches, programs compiled,
+        backend resolutions)."""
         return (self.metrics.prometheus_text() +
                 GLOBAL_REGISTRY.prometheus_text())
 

@@ -22,6 +22,13 @@ mostly idle between requests.  This tier inverts the design:
   fused kernel passes (``SchedulerStats.cross_tenant_*``), which is
   where the throughput win comes from: the paper's multi-query
   optimization applied across users.
+* **Request spans** — with the service's tracer on, each request gets an
+  id ``rid`` that its spans share: ``tier.read`` (the request after its
+  first line, through the decoded body), ``tier.queue`` (admission
+  through the start of the ``execute_many`` call that serves it),
+  ``tier.resume`` (that call returning, through the request's coroutine
+  running again) and ``tier.respond`` (encoding and writing the reply).
+  The service's own spans carry the same ``rid``.
 * **Streaming sessions** — ``POST /v1/query`` with ``"stream": true``
   returns a chunked NDJSON response, one cursor-paged ``/v1`` payload
   per chunk until the ranking is exhausted; continuation pages re-enter
@@ -42,8 +49,10 @@ from __future__ import annotations
 import argparse
 import asyncio
 import dataclasses
+import itertools
 import json
 import threading
+import time
 from concurrent.futures import ThreadPoolExecutor
 from http.client import responses as _http_reasons
 from typing import Optional
@@ -78,14 +87,17 @@ class TierStats:
 
 
 class _Pending:
-    """One admitted request: its execute_many items and the future the
-    connection coroutine awaits."""
+    """One admitted request: its execute_many items, the future the
+    connection coroutine awaits, and (with tracing on) when it was
+    admitted and when the call that served it returned."""
 
-    __slots__ = ("items", "future")
+    __slots__ = ("items", "future", "rid", "t_admit", "t_done")
 
-    def __init__(self, items: list, future: asyncio.Future):
+    def __init__(self, items: list, future: asyncio.Future, rid=None):
         self.items = items
         self.future = future
+        self.rid = rid
+        self.t_admit = self.t_done = 0.0
 
 
 class AsyncTier:
@@ -106,6 +118,8 @@ class AsyncTier:
         self.max_inflight_batches = max(int(max_inflight_batches), 1)
         self.stream_page_limit = stream_page_limit
         self.stats = TierStats()
+        self.tracer = service.tracer
+        self._rids = itertools.count(1)
         self.admission = AdmissionController(
             rate=tenant_rate, burst=tenant_burst, depth=queue_depth,
             weights=tenant_weights)
@@ -150,12 +164,10 @@ class AsyncTier:
 
     # -- HTTP plumbing ----------------------------------------------------
     @staticmethod
-    async def _read_request(reader: asyncio.StreamReader):
-        """→ (method, target, headers, body) or None on EOF/garbage."""
+    async def _read_request(reader: asyncio.StreamReader, line: bytes):
+        """The request whose first line is ``line`` → (method, target,
+        headers, body) or None on EOF/garbage."""
         try:
-            line = await reader.readline()
-            if not line:
-                return None
             parts = line.decode("latin-1").split()
             if len(parts) < 2:
                 return None
@@ -227,15 +239,27 @@ class AsyncTier:
         self.stats.connections_open += 1
         try:
             while not self._closing:
-                req = await self._read_request(reader)
-                if req is None:
+                try:                # idle until the client's next request
+                    line = await reader.readline()
+                except (ConnectionError, ValueError):
                     break
-                method, target, headers, body = req
+                if not line:
+                    break
+                rid = next(self._rids)
+                with self.tracer.span("tier.read", keep=False) as sp:
+                    sp.set(rid=rid)
+                    req = await self._read_request(reader, line)
+                    if req is None:
+                        break
+                    method, target, headers, body = req
+                    if method == "POST":
+                        body = _decode(body)
                 self.stats.requests_total += 1
                 keep = headers.get("connection", "").lower() != "close"
                 try:
                     streamed = await self._route(method, target, headers,
-                                                 body, writer, keep=keep)
+                                                 body, writer, keep=keep,
+                                                 rid=rid)
                 except (ConnectionError, OSError):
                     break
                 self.stats.completed += 1
@@ -250,35 +274,42 @@ class AsyncTier:
 
     # -- routing ----------------------------------------------------------
     async def _route(self, method: str, target: str, headers: dict,
-                     body: bytes, writer: asyncio.StreamWriter, *,
-                     keep: bool) -> bool:
+                     body, writer: asyncio.StreamWriter, *,
+                     keep: bool, rid: int) -> bool:
         """Serve one request; → True when the response was streamed (the
-        connection closes afterwards)."""
+        connection closes afterwards).  A POST's ``body`` is its decoded
+        JSON, or the exception decoding raised."""
         parsed = urlparse(target)
         path = parsed.path
         v1 = path.startswith("/v1/")
         tenant = headers.get("x-tenant", "default")
         loop = asyncio.get_running_loop()
 
-        async def send(payload: bytes) -> None:
-            writer.write(payload)
-            await writer.drain()
+        async def send(encode) -> None:
+            """Encode the reply (``encode()`` → bytes) and write it, inside
+            the request's ``tier.respond`` span."""
+            with self.tracer.span("tier.respond", keep=False) as sp:
+                sp.set(rid=rid)
+                writer.write(encode())
+                await writer.drain()
+
+        def reply(obj):
+            return lambda: self._json_response(200, obj, close=not keep)
 
         try:
             if method == "GET":
                 if path in ("/healthz", "/v1/healthz"):
-                    await send(self._json_response(200, {"ok": True},
-                                                   close=not keep))
+                    await send(reply({"ok": True}))
                     return False
                 if path in ("/stats", "/v1/stats"):
                     out = await loop.run_in_executor(self._pool,
                                                      self.service.stats)
-                    await send(self._json_response(200, out, close=not keep))
+                    await send(reply(out))
                     return False
                 if path in ("/metrics", "/v1/metrics"):
                     text = await loop.run_in_executor(
                         self._pool, self.service.metrics_text)
-                    await send(self._response_bytes(
+                    await send(lambda: self._response_bytes(
                         200, text.encode(),
                         content_type="text/plain; version=0.0.4; "
                                      "charset=utf-8", close=not keep))
@@ -294,7 +325,7 @@ class AsyncTier:
                     out = await loop.run_in_executor(
                         self._pool,
                         lambda: self.service.trace(qid, fmt=fmt))
-                    await send(self._json_response(200, out, close=not keep))
+                    await send(reply(out))
                     return False
                 m = _SESSION_PAGE_RE.match(path)
                 if m:                       # legacy GET session page
@@ -305,9 +336,9 @@ class AsyncTier:
                     except ValueError:
                         raise ValueError(f"bad page size k={qs['k'][0]!r}")
                     payload = await self._execute_one(
-                        tenant, {"op": "page", "session_id": sid, "k": k})
-                    await send(self._json_response(200, payload,
-                                                   close=not keep))
+                        tenant, {"op": "page", "session_id": sid, "k": k},
+                        rid=rid)
+                    await send(reply(payload))
                     return False
                 raise NotFoundError(f"no route {path}")
 
@@ -318,26 +349,28 @@ class AsyncTier:
                     out = await loop.run_in_executor(
                         self._pool,
                         lambda: {"dropped": self.service.drop_session(sid)})
-                    await send(self._json_response(200, out, close=not keep))
+                    await send(reply(out))
                     return False
                 raise NotFoundError(f"no route {path}")
 
             if method != "POST":
                 raise NotFoundError(f"no route {method} {path}")
 
-            req_body = json.loads(body or b"{}")
+            if isinstance(body, Exception):
+                raise body
+            req_body = body
 
             if path in ("/query", "/v1/query"):
                 kw = routes.query_kwargs(req_body)
                 if v1 and req_body.get("stream"):
-                    await self._stream_query(tenant, req_body, writer)
+                    await self._stream_query(tenant, req_body, writer, rid)
                     return True
                 item = {"op": "query", "sql": kw["sql"], "rois": kw["rois"],
                         "session": kw["session"],
                         "page_size": kw["page_size"]}
-                payload = await self._execute_one(tenant, item)
-                out = routes.shape_query(payload) if v1 else payload
-                await send(self._json_response(200, out, close=not keep))
+                payload = await self._execute_one(tenant, item, rid=rid)
+                await send(reply(routes.shape_query(payload) if v1
+                                 else payload))
                 return False
 
             if path in ("/workload", "/v1/workload"):
@@ -345,21 +378,21 @@ class AsyncTier:
                 rois = routes.parse_rois(req_body)
                 items = [{"op": "query", "sql": sql, "rois": rois}
                          for sql in sqls]
-                results = await self._submit(tenant, items)
+                results = await self._submit(tenant, items, rid=rid)
                 for status, value in results:
                     if status == "error":   # legacy submit_batch semantics:
                         raise value         # one bad query fails the batch
                 payloads = [value for _, value in results]
-                out = (routes.shape_workload(payloads) if v1 else payloads)
-                await send(self._json_response(200, out, close=not keep))
+                await send(reply(routes.shape_workload(payloads) if v1
+                                 else payloads))
                 return False
 
             if path == "/v1/page":
                 sid, k = routes.page_request(req_body)
                 payload = await self._execute_one(
-                    tenant, {"op": "page", "session_id": sid, "k": k})
-                await send(self._json_response(200, routes.shape_page(payload),
-                                               close=not keep))
+                    tenant, {"op": "page", "session_id": sid, "k": k},
+                    rid=rid)
+                await send(reply(routes.shape_page(payload)))
                 return False
 
             if path in ("/ingest", "/v1/ingest"):
@@ -367,9 +400,7 @@ class AsyncTier:
                 self.admission.charge(tenant)
                 out = await loop.run_in_executor(
                     self._pool, lambda: self.service.ingest(**kw))
-                await send(self._json_response(
-                    200, routes.shape_ingest(out) if v1 else out,
-                    close=not keep))
+                await send(reply(routes.shape_ingest(out) if v1 else out))
                 return False
 
             if path in ("/delete", "/v1/delete"):
@@ -377,9 +408,7 @@ class AsyncTier:
                 self.admission.charge(tenant)
                 out = await loop.run_in_executor(
                     self._pool, lambda: self.service.delete(ids))
-                await send(self._json_response(
-                    200, routes.shape_delete(out) if v1 else out,
-                    close=not keep))
+                await send(reply(routes.shape_delete(out) if v1 else out))
                 return False
 
             if path == "/v1/session/drop":
@@ -389,31 +418,40 @@ class AsyncTier:
                 out = await loop.run_in_executor(
                     self._pool,
                     lambda: {"dropped": self.service.drop_session(sid)})
-                await send(self._json_response(200, out, close=not keep))
+                await send(reply(out))
                 return False
 
             raise NotFoundError(f"no route {path}")
         except (ConnectionError, OSError):
             raise
         except Exception as e:          # noqa: BLE001 — serving loop
-            await send(self._error_response(e, v1=v1))
+            await send(lambda: self._error_response(e, v1=v1))
             return False
 
     # -- admitted execution ----------------------------------------------
-    async def _submit(self, tenant: str, items: list, *,
+    async def _submit(self, tenant: str, items: list, *, rid=None,
                       force: bool = False) -> list:
         """Admit a request's items and await the dispatcher's results
         (aligned ``("ok", payload) | ("error", exc)`` tuples)."""
         for item in items:
             item["tenant"] = tenant
+            item["rid"] = rid
         future: asyncio.Future = asyncio.get_running_loop().create_future()
-        self.admission.admit(tenant, _Pending(items, future), force=force)
+        pending = _Pending(items, future, rid)
+        traced = self.tracer.enabled
+        if traced:
+            pending.t_admit = time.perf_counter()
+        self.admission.admit(tenant, pending, force=force)
         self._wake.set()
-        return await future
+        results = await future
+        if traced and pending.t_done:
+            self.tracer.record("tier.resume", pending.t_done,
+                               time.perf_counter(), rid=rid)
+        return results
 
-    async def _execute_one(self, tenant: str, item: dict, *,
+    async def _execute_one(self, tenant: str, item: dict, *, rid=None,
                            force: bool = False) -> dict:
-        status, value = (await self._submit(tenant, [item],
+        status, value = (await self._submit(tenant, [item], rid=rid,
                                             force=force))[0]
         if status == "error":
             raise value
@@ -441,7 +479,7 @@ class AsyncTier:
             items.extend(p.items)
         try:
             results = await asyncio.get_running_loop().run_in_executor(
-                self._pool, self.service.execute_many, items)
+                self._pool, self._execute, pendings, items)
         except Exception as e:          # noqa: BLE001 — batch-level fault
             for p in pendings:
                 if not p.future.done():
@@ -459,9 +497,27 @@ class AsyncTier:
             self._inflight.release()
             self._wake.set()
 
+    def _execute(self, pendings: list, items: list) -> list:
+        """On a worker thread: one ``execute_many`` call for the batch,
+        with each request's wait in the tier (``tier.queue``) recorded up
+        to the call and the call's return stamped for ``tier.resume``."""
+        tracer = self.tracer
+        if not tracer.enabled:
+            return self.service.execute_many(items)
+        t0 = time.perf_counter()
+        for p in pendings:
+            if p.t_admit:
+                tracer.record("tier.queue", p.t_admit, t0, rid=p.rid)
+        try:
+            return self.service.execute_many(items)
+        finally:
+            t1 = time.perf_counter()
+            for p in pendings:
+                p.t_done = t1
+
     # -- streaming --------------------------------------------------------
     async def _stream_query(self, tenant: str, req_body: dict,
-                            writer: asyncio.StreamWriter) -> None:
+                            writer: asyncio.StreamWriter, rid=None) -> None:
         """Chunked NDJSON: the opening page, then every continuation page
         until the ranking is exhausted.  The open is admitted normally;
         continuation pages are depth-exempt (``force=True``) — the tier
@@ -469,7 +525,7 @@ class AsyncTier:
         kw = routes.query_kwargs(req_body)
         item = {"op": "query", "sql": kw["sql"], "rois": kw["rois"],
                 "session": True, "page_size": kw["page_size"]}
-        payload = await self._execute_one(tenant, item)
+        payload = await self._execute_one(tenant, item, rid=rid)
         if "session" not in payload:
             raise ValueError("stream requires a ranking (ORDER BY … LIMIT) "
                              "query")
@@ -494,7 +550,7 @@ class AsyncTier:
             while not shaped["exhausted"] and pages < self.stream_page_limit:
                 payload = await self._execute_one(
                     tenant, {"op": "page", "session_id": sid, "k": k},
-                    force=True)
+                    rid=rid, force=True)
                 shaped = routes.shape_page(payload)
                 await chunk(shaped)
                 pages += 1
@@ -506,6 +562,15 @@ class AsyncTier:
                 pass
         writer.write(b"0\r\n\r\n")
         await writer.drain()
+
+
+def _decode(body: bytes):
+    """A POST body's JSON, or the exception decoding it raised (served
+    as the route's error reply)."""
+    try:
+        return json.loads(body or b"{}")
+    except Exception as e:          # noqa: BLE001 — replied as an error
+        return e
 
 
 def _tier_sampler(tier: AsyncTier):

@@ -163,32 +163,41 @@ class FusedScheduler:
         jobs = [j for j in jobs if j is not None]
         owns_cache = self.store.enable_cache()
         try:
-            while True:
-                takes = []
-                for job in jobs:
-                    if job.finished():
-                        continue
-                    batch = job.take_batch()
-                    if len(batch):
-                        takes.append((job, batch))
-                if not takes:
-                    break
-                self.stats.rounds += 1
-                fused = [(j, b) for j, b in takes if _fusable(j)]
-                pair_fused = [(j, b) for j, b in takes if _pair_fusable(j)]
-                direct = [(j, b) for j, b in takes
-                          if not (_fusable(j) or _pair_fusable(j))]
-                if fused:
-                    self._fused_pass(fused)
-                if pair_fused:
-                    self._fused_pair_pass(pair_fused)
-                for job, batch in direct:
-                    self.stats.fallback_batches += 1
-                    job.self_verify(batch)
+            with _trace.span("scheduler.drive") as sp:
+                sp.set(jobs=len(jobs))
+                while self._round(jobs):
+                    pass
         finally:
             self._tenant_of = {}
             if owns_cache:
                 self.store.clear_cache()
+
+    def _round(self, jobs) -> bool:
+        """One round: a batch from every unfinished job, fused where the
+        jobs allow.  → False when no job had anything left to verify."""
+        with _trace.span("scheduler.round"):
+            takes = []
+            for job in jobs:
+                if job.finished():
+                    continue
+                batch = job.take_batch()
+                if len(batch):
+                    takes.append((job, batch))
+            if not takes:
+                return False
+            self.stats.rounds += 1
+            fused = [(j, b) for j, b in takes if _fusable(j)]
+            pair_fused = [(j, b) for j, b in takes if _pair_fusable(j)]
+            direct = [(j, b) for j, b in takes
+                      if not (_fusable(j) or _pair_fusable(j))]
+            if fused:
+                self._fused_pass(fused)
+            if pair_fused:
+                self._fused_pair_pass(pair_fused)
+            for job, batch in direct:
+                self.stats.fallback_batches += 1
+                job.self_verify(batch)
+            return True
 
     # -- the fused kernel pass -------------------------------------------
     def _fused_pass(self, pairs) -> None:
