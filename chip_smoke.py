@@ -202,8 +202,9 @@ def kernel_cases(store, pstore, rois):
     import numpy as np
 
     b, q = CHECK_ROWS, 4
-    m = store.device_masks()[:b]
-    p = pstore.device_masks()[:b]
+    # the resident tiers hold 2-D rows; the kernels take (B, H, W') masks
+    m = store.device_masks()[:b].reshape((b,) + store.row_shape)
+    p = pstore.device_masks()[:b].reshape((b,) + pstore.row_shape)
     r = jnp.asarray(rois[:b], jnp.int32)
     rq = jnp.asarray(np.stack([np.roll(rois[:b], i, axis=0)
                                for i in range(q)]), jnp.int32)

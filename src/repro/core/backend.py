@@ -362,11 +362,27 @@ def _device_pair_cells(tables, pos_a, pos_b, ks, rois, rb, cb, stat):
     return pair_cell_bounds_jnp(stat, lo_a, hi_a, lo_b, hi_b, rois, rb, cb)
 
 
-@jax.jit
-def _device_multi_counts(masks, pos, rois_q, lvs, uvs):
-    """Gather a verification batch from the resident mask array and answer
+def _batch(rows, pos, row_shape):
+    """The resident rows at ``pos`` as a ``(len(pos),) + row_shape`` batch.
+
+    ``rows`` is the store's 2-D resident array (``MaskStore.device_masks``,
+    one mask per row): whole rows gather as they lie, and only the batch
+    is reshaped.  The same gather from a 3-D ``(n, H, W')`` array would
+    first relayout the whole store (DESIGN.md §7).
+
+    The barrier keeps XLA from fusing the batch's reshape into the Pallas
+    call's operand: fused, a batch of 104 rows took the TPU compiler 7 s
+    instead of 1 s, and every new batch size of a warm-up paid it."""
+    return jax.lax.optimization_barrier(
+        rows[pos].reshape(pos.shape + row_shape))
+
+
+@functools.partial(jax.jit, static_argnames=("row_shape",))
+def _device_multi_counts(masks, pos, rois_q, lvs, uvs, row_shape):
+    """Gather a verification batch from the resident mask rows and answer
     Q CP descriptors in one fused kernel pass."""
-    return kops.cp_count_multi(masks[pos], rois_q, lvs, uvs)
+    return kops.cp_count_multi(_batch(masks, pos, row_shape), rois_q, lvs,
+                               uvs)
 
 
 @functools.partial(jax.jit, static_argnames=("k",))
@@ -375,34 +391,43 @@ def _device_kth_index(pes, definite, k):
     return jax.lax.top_k(masked, k)[1][k - 1]
 
 
-@functools.partial(jax.jit, static_argnames=("s",))
-def _device_group_counts(masks, flat_pos, rois, thresh, s):
-    grp = masks[flat_pos]
-    n = flat_pos.shape[0] // s
-    grp = grp.reshape(n, s, masks.shape[1], masks.shape[2])
-    return kops.mask_agg_counts(grp, rois, thresh)
+@functools.partial(jax.jit, static_argnames=("s", "row_shape"))
+def _device_group_counts(masks, flat_pos, rois, thresh, s, row_shape):
+    grp = _batch(masks, flat_pos, row_shape)
+    return kops.mask_agg_counts(grp.reshape((-1, s) + row_shape), rois,
+                                thresh)
 
 
-@jax.jit
-def _device_multi_counts_packed(packed, pos, rois_q, lvs, uvs):
+@functools.partial(jax.jit, static_argnames=("row_shape",))
+def _device_multi_counts_packed(packed, pos, rois_q, lvs, uvs, row_shape):
     """Packed-tier sibling of :func:`_device_multi_counts`."""
-    return kops.cp_count_multi_packed(packed[pos], rois_q, lvs, uvs)
+    return kops.cp_count_multi_packed(_batch(packed, pos, row_shape), rois_q,
+                                      lvs, uvs)
 
 
-@functools.partial(jax.jit, static_argnames=("s",))
-def _device_group_counts_packed(packed, flat_pos, rois, thresh, s):
-    grp = packed[flat_pos]
-    n = flat_pos.shape[0] // s
-    grp = grp.reshape(n, s, packed.shape[1], packed.shape[2])
-    return kops.mask_agg_counts_packed(grp, rois, thresh)
+@functools.partial(jax.jit, static_argnames=("s", "row_shape"))
+def _device_group_counts_packed(packed, flat_pos, rois, thresh, s, row_shape):
+    grp = _batch(packed, flat_pos, row_shape)
+    return kops.mask_agg_counts_packed(grp.reshape((-1, s) + row_shape), rois,
+                                       thresh)
 
 
-@jax.jit
-def _device_fused_verify(packed, pos, rois_q, lvs, uvs, decided, lb):
+@functools.partial(jax.jit, static_argnames=("row_shape",))
+def _device_fused_verify(packed, pos, rois_q, lvs, uvs, decided, lb,
+                         row_shape):
     """Gather a verification batch from the resident packed words and run
     the bounds+verify megakernel — one launch for the whole batch."""
-    return kops.fused_bounds_verify(packed[pos], rois_q, lvs, uvs,
-                                    decided, lb)
+    return kops.fused_bounds_verify(_batch(packed, pos, row_shape), rois_q,
+                                    lvs, uvs, decided, lb)
+
+
+@functools.partial(jax.jit, static_argnames=("row_shape",))
+def gather(masks, pos, row_shape):
+    """One role of the fused pair pass: the resident rows at ``pos`` as a
+    ``(B,) + row_shape`` batch, left on the device.  Its program is named
+    ``gather`` in the device trace, where the verification roofline
+    counts it among the verification steps."""
+    return _batch(masks, pos, row_shape)
 
 
 class _KthValueMixin:
@@ -470,7 +495,8 @@ class DeviceBackend(_KthValueMixin, ExecBackend):
         self.cfg = store.cfg
         self.stats = BackendStats()
         self._packed = is_packed(store)   # resident array is uint32 words
-        self._masks = store.device_masks()
+        self._masks = store.device_masks()          # 2-D: one mask per row
+        self._row_shape = store.row_shape           # what a kernel sees
         self._tables = store.chi_table
         self._epoch = getattr(store, "epoch", 0)
         self._rb = jnp.asarray(self.cfg.row_bounds, jnp.int32)
@@ -598,7 +624,7 @@ class DeviceBackend(_KthValueMixin, ExecBackend):
         rois_q, lvs, uvs = spec_arrays(
             [(ctx.resolve_rois(t.roi, pos), t.lv, t.uv) for t in terms])
         counts = self._step(*self._multi(), self._masks, np.asarray(pos),
-                            rois_q, lvs, uvs)
+                            rois_q, lvs, uvs, row_shape=self._row_shape)
         return {t: counts[i].astype(np.float64)
                 for i, t in enumerate(terms)}
 
@@ -606,7 +632,7 @@ class DeviceBackend(_KthValueMixin, ExecBackend):
                             decided, lb):
         return self._step("_device_fused_verify", _device_fused_verify,
                           self._masks, np.asarray(pos), rois_q, lvs, uvs,
-                          decided, lb)
+                          decided, lb, row_shape=self._row_shape)
 
     def topk_candidates(self, lb, ub, k, desc, definite, possible):
         if k <= 0 or int(np.count_nonzero(definite)) < k:
@@ -627,27 +653,28 @@ class DeviceBackend(_KthValueMixin, ExecBackend):
             counts = self._step(
                 "_device_group_counts_packed", _device_group_counts_packed,
                 self._masks, flat, rois, np.asarray(node.thresh, np.float32),
-                pick=pick, s=int(s))
+                pick=pick, s=int(s), row_shape=self._row_shape)
         else:
             counts = self._step(
                 "_device_group_counts", _device_group_counts, self._masks,
                 flat, rois, np.asarray(node.thresh, self._masks.dtype),
-                pick=pick, s=int(s))
+                pick=pick, s=int(s), row_shape=self._row_shape)
         return counts.astype(np.float64)
 
     def fused_counts(self, store, positions, specs):
         rois_q, lvs, uvs = spec_arrays(specs)
         return self._step(*self._multi(), self._masks,
-                          np.asarray(positions), rois_q, lvs, uvs)
+                          np.asarray(positions), rois_q, lvs, uvs,
+                          row_shape=self._row_shape)
 
     def fused_pair_counts(self, store, pos_a, pos_b, specs):
         # Both roles are resident (the store's one HBM mask array); gather
         # each role ONCE and answer every descriptor against the gathered
         # batch — zero metered bytes, 2 gathers regardless of Q.
-        a = self._step("gather", _gather, self._masks, np.asarray(pos_a),
-                       fetch=False)
-        b = self._step("gather", _gather, self._masks, np.asarray(pos_b),
-                       fetch=False)
+        a = self._step("gather", gather, self._masks, np.asarray(pos_a),
+                       fetch=False, row_shape=self._row_shape)
+        b = self._step("gather", gather, self._masks, np.asarray(pos_b),
+                       fetch=False, row_shape=self._row_shape)
         if self._packed:
             step, kernel, tdt = ("pair_counts_packed",
                                  kops.pair_counts_packed, np.float32)
@@ -660,11 +687,6 @@ class DeviceBackend(_KthValueMixin, ExecBackend):
             for row, counts in enumerate(trio):
                 out[qi, row] = counts
         return out
-
-
-def _gather(masks, pos):
-    """Rows of the resident array (an eager device gather)."""
-    return masks[pos]
 
 
 # ---------------------------------------------------------------------------

@@ -587,8 +587,7 @@ class MaskStore:
                 self._resident = np.concatenate([self._resident, stored])
         if self._device_masks is not None:
             self._device_masks = jnp.concatenate(
-                [self._device_masks,
-                 jnp.asarray(stored, self._device_masks.dtype)])
+                [self._device_masks, jnp.asarray(self._rows(stored))])
         # CHI: new chunk; no existing rows are copied
         self._chi_chunks.append(chunk)
         if self._chi_dev is not None:
@@ -696,8 +695,7 @@ class MaskStore:
                 self._resident = res
         if self._device_masks is not None:
             self._device_masks = self._device_masks.at[
-                jnp.asarray(positions)].set(
-                jnp.asarray(stored, self._device_masks.dtype))
+                jnp.asarray(positions)].set(jnp.asarray(self._rows(stored)))
         # shared-load cache: the bytes at these positions changed
         if self._cache_map is not None:
             rows = self._cache_map[positions]
@@ -825,14 +823,24 @@ class MaskStore:
                 self._resident = out
         return self._resident
 
+    def _rows(self, masks) -> np.ndarray:
+        """Stored masks ``(n,) + row_shape`` as 2-D rows ``(n, H·W')``: a
+        host reshape, so no bytes move."""
+        h, w = self.row_shape
+        return np.asarray(masks, self.row_dtype).reshape(len(masks), h * w)
+
     def device_masks(self):
         """:meth:`resident_masks` pinned in device memory (jnp, cached) —
-        the HBM-resident tier the device backend verifies against.  Once
-        materialized, mutations maintain it incrementally: appends
-        ``device_put`` only the new rows, updates scatter the changed rows,
-        deletes gather the survivors."""
+        the HBM-resident tier the device backend verifies against — held
+        as 2-D rows ``(n, H·W')``: ``row_shape`` flattened, so one mask is
+        one row (DESIGN.md §7).  A 3-D array gets a compact TPU layout
+        whose row gather relayouts the whole store first; whole rows of
+        the 2-D array gather as they lie.  Once materialized, mutations
+        maintain it incrementally: appends ``device_put`` only the new
+        rows, updates scatter the changed rows, deletes gather the
+        survivors."""
         if self._device_masks is None:
-            self._device_masks = jnp.asarray(self.resident_masks())
+            self._device_masks = jnp.asarray(self._rows(self.resident_masks()))
         return self._device_masks
 
     # -- mask-byte access (the metered path) --------------------------------

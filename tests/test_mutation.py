@@ -581,3 +581,56 @@ def test_stale_run_error_surfaces_as_conflict():
     plan = LogicalPlan(predicate=Cmp(CP(None, 0.2, 0.6), ">", 100.0))
     for backend in ("host", "device", "mesh"):
         run_plan(store, plan, backend=backend)
+
+
+@pytest.mark.parametrize("packed", [False, True], ids=["float", "packed"])
+def test_device_rows_follow_append_update_delete(packed):
+    """The device tier holds one mask per 2-D row, ``(n, H·W')``.  After
+    each append, update and delete it equals the host copy row for row,
+    and the device backend answers a plan through each of its gathering
+    steps (fused CP counts, the megakernel, MASK_AGG groups, the pair
+    pass) exactly as the host backend does."""
+    from repro.core.backend import get_backend
+    from repro.core.exprs import AggCP, pair_iou
+
+    data = _binary_data if packed else _data
+    masks, meta = data(B)
+    store = MaskStore.create_memory(masks, meta, CFG, packed=packed)
+    get_backend(store, "device")          # the resident upload, epoch 0
+    plans = [
+        LogicalPlan(order_by=CP(None, 0.5, 1.5), k=5),
+        LogicalPlan(predicate=Cmp(CP((4, 4, 28, 28), 0.5, 1.5), ">", 40.0),
+                    order_by=CP((2, 6, 30, 26), 0.5, 1.5), k=4),
+        LogicalPlan(select="image_id", order_by=AggCP("intersect", 0.5, None),
+                    k=3),
+        LogicalPlan(order_by=pair_iou(1, 2, 0.6, 0.6), k=3, desc=False),
+    ]
+    rng = np.random.default_rng(11)
+
+    def check():
+        n = len(store)
+        rows = np.asarray(store.device_masks())
+        assert rows.shape == (n, store.row_shape[0] * store.row_shape[1])
+        np.testing.assert_array_equal(
+            rows, store.resident_masks().reshape(n, -1))
+        verified = 0
+        for plan in plans:
+            (want, want_scores), want_stats = run_plan(store, plan,
+                                                       verify_batch=4)
+            (got, got_scores), got_stats = run_plan(store, plan,
+                                                    verify_batch=4,
+                                                    backend="device")
+            assert list(got) == list(want), plan
+            np.testing.assert_array_equal(got_scores, want_scores)
+            assert got_stats.n_verified == want_stats.n_verified, plan
+            verified += got_stats.n_verified
+        assert verified > 0
+
+    add, add_meta = data(4, seed=3, id_base=1000)
+    store.append(add, add_meta)
+    check()
+    upd = rng.choice(store.mask_ids, size=3, replace=False)
+    store.update(upd, data(3, seed=5)[0])
+    check()
+    store.delete(rng.choice(store.mask_ids, size=2, replace=False))
+    check()

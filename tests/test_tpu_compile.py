@@ -1,8 +1,8 @@
 """Compile the kernel wrappers with the TPU compiler for a described (not
 attached) v5e chip, at the deployment's shapes: B = 256 float32 224×224
 masks, packed rows of 7 words, Q = 4 descriptors, S = 2 mask types, CHI
-grid 16 × 16 bins.  Also one sharded kernel step on ``v5e:2x2`` and the
-device backend's verification step over the paper-scale resident store.
+grid 16 × 16 bins.  Also one sharded kernel step on ``v5e:2x2``, and the
+device backend's gathering steps over resident stores of 2-D mask rows.
 
 Nothing runs: a compile that passes here is not a chip run.  It catches
 what the chip's compiler refuses (block shapes off the (8, 128) tiling,
@@ -11,6 +11,7 @@ kernels that cannot be partitioned) at no chip time.
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -116,19 +117,75 @@ def test_mesh_verify_step_compiles_on_v5e_2x2(topo, tpu_dispatch):
 def test_device_verify_step_compiles_over_paper_scale_store(one_chip,
                                                             tpu_dispatch):
     """The device backend's verification step gathers a batch from the
-    resident paper-scale store (22,275 masks, 4.47 GB) and fits the
-    chip's 16 GB."""
+    resident paper-scale store (22,275 masks, 4.47 GB, held as 2-D rows)
+    and fits the chip's 16 GB."""
     from repro.core.backend import _device_multi_counts
 
     def s(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
     compiled = _device_multi_counts.lower(
-        s((PAPER_MASKS, H, W), jnp.float32), s((B,), jnp.int32),
+        s((PAPER_MASKS, H * W), jnp.float32), s((B,), jnp.int32),
         s((Q, B, 4), jnp.int32), s((Q,), jnp.float32),
-        s((Q,), jnp.float32)).compile()
+        s((Q,), jnp.float32), row_shape=(H, W)).compile()
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+# The resident stores of the gathering steps: the packed tier's 22,274
+# masks of 448 rows × 14 words (0.56 GB), and 4,000 float 448 × 448 masks
+# (3.2 GB; float rows at 12,000 masks exceed the chip in either form).
+PACKED_STORE = (22274, (448, 14), jnp.uint32)
+FLOAT_STORE = (4000, (448, 448), jnp.float32)
+GATHER_STEPS = {
+    "_device_multi_counts_packed": PACKED_STORE,
+    "_device_group_counts_packed": PACKED_STORE,
+    "_device_fused_verify": PACKED_STORE,
+    "gather": PACKED_STORE,
+    "_device_multi_counts": FLOAT_STORE,
+    "_device_group_counts": FLOAT_STORE,
+}
+
+
+@pytest.mark.parametrize("name", sorted(GATHER_STEPS))
+def test_device_gather_step_reads_resident_rows_without_relayout(
+        name, one_chip, tpu_dispatch):
+    """Each device step gathers its batch from the store's 2-D rows.  Over
+    a 3-D ``(n, H, W')`` store the chip's compact layout makes XLA copy
+    the whole store before the gather (0.73 GB of temporaries for the
+    packed steps, 3.9 GB for the float ones).  Packed rows gather as they
+    lie; float rows of 784 KB still pass through column slabs of the whole
+    store, but never more than its own size beside the batch."""
+    from repro.core import backend as be
+
+    n, row_shape, dtype = GATHER_STEPS[name]
+    row = row_shape[0] * row_shape[1]
+
+    def s(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    rows, pos, qrois, vals = (s((n, row), dtype), s((B,)), s((Q, B, 4)),
+                              s((Q,), jnp.float32))
+    thresh = s((), jnp.float32)
+    args = {
+        "_device_multi_counts_packed": (rows, pos, qrois, vals, vals),
+        "_device_multi_counts": (rows, pos, qrois, vals, vals),
+        "_device_group_counts_packed": (rows, pos, s((B // S, 4)), thresh),
+        "_device_group_counts": (rows, pos, s((B // S, 4)), thresh),
+        "_device_fused_verify": (rows, pos, qrois, vals, vals, s((Q, B)),
+                                 s((Q, B))),
+        "gather": (rows, pos),
+    }[name]
+    static = {"s": S} if "group" in name else {}
+    compiled = getattr(be, name).lower(*args, row_shape=row_shape,
+                                       **static).compile()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    if dtype == jnp.uint32:
+        store_copy = re.compile(rf"= u32\[{n},[^=]* copy\(")
+        assert not [ln for ln in compiled.as_text().splitlines()
+                    if store_copy.search(ln)]
+        assert temp < 0.1e9
+    else:
+        assert temp <= (n + B) * row * np.dtype(dtype).itemsize
 
 
 def test_device_bounds_step_gathers_chi_rows_without_relayout(one_chip):
