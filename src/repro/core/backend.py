@@ -196,6 +196,13 @@ class ExecBackend:
         descriptors/verdicts → (Q, B) int32 exact counts."""
         raise NotImplementedError
 
+    @property
+    def gathered_bytes(self) -> int:
+        """Bytes of resident mask rows this backend's device steps have
+        gathered (monotonic).  Backends that hold no rows on a device
+        gather none."""
+        return 0
+
     def fused_counts(self, store, positions: np.ndarray,
                      specs) -> np.ndarray:
         """The scheduler's fused pass: Q ``(rois, lv, uv)`` descriptors
@@ -365,10 +372,12 @@ def _device_pair_cells(tables, pos_a, pos_b, ks, rois, rb, cb, stat):
 def _batch(rows, pos, row_shape):
     """The resident rows at ``pos`` as a ``(len(pos),) + row_shape`` batch.
 
-    ``rows`` is the store's 2-D resident array (``MaskStore.device_masks``,
-    one mask per row): whole rows gather as they lie, and only the batch
-    is reshaped.  The same gather from a 3-D ``(n, H, W')`` array would
-    first relayout the whole store (DESIGN.md §7).
+    ``rows`` is the store's resident array (``MaskStore.device_masks``,
+    one mask per leading index: packed words as 2-D rows, float pixels in
+    lanes of 128): whole masks gather as they lie, and only the batch is
+    reshaped.  The same gather from a 3-D ``(n, H, W')`` array would first
+    relayout the whole store, and from 2-D float rows it passes the whole
+    store through column slabs (DESIGN.md §7).
 
     The barrier keeps XLA from fusing the batch's reshape into the Pallas
     call's operand: fused, a batch of 104 rows took the TPU compiler 7 s
@@ -472,11 +481,14 @@ class BackendStats:
     """Host↔device traffic of the device backend (monotonic, always on;
     ``/metrics`` as ``masksearch_backend_*``): bytes of step arguments
     converted host→device, bytes fetched device→host, calls into jitted
-    steps or eager device ops, and output arrays fetched."""
+    steps or eager device ops, output arrays fetched, and bytes of
+    resident mask rows the verification steps gathered (rows × one
+    stored row)."""
     h2d_bytes: int = 0
     d2h_bytes: int = 0
     device_calls: int = 0
     fetches: int = 0
+    gathered_bytes: int = 0
 
 
 class DeviceBackend(_KthValueMixin, ExecBackend):
@@ -495,8 +507,9 @@ class DeviceBackend(_KthValueMixin, ExecBackend):
         self.cfg = store.cfg
         self.stats = BackendStats()
         self._packed = is_packed(store)   # resident array is uint32 words
-        self._masks = store.device_masks()          # 2-D: one mask per row
+        self._masks = store.device_masks()          # one mask per index
         self._row_shape = store.row_shape           # what a kernel sees
+        self._row_nbytes = store.row_nbytes
         self._tables = store.chi_table
         self._epoch = getattr(store, "epoch", 0)
         self._rb = jnp.asarray(self.cfg.row_bounds, jnp.int32)
@@ -534,18 +547,24 @@ class DeviceBackend(_KthValueMixin, ExecBackend):
         self.stats.d2h_bytes += host.nbytes
         return host
 
+    @property
+    def gathered_bytes(self) -> int:
+        return self.stats.gathered_bytes
+
     def _step(self, step: str, fn, *args, pick: int | None = None,
-              fetch: bool = True, **static):
+              fetch: bool = True, rows: int = 0, **static):
         """One call into the device: host ``args`` converted, ``fn``
         dispatched (``static`` keywords passed through), output ``pick``
         of a tuple chosen on the device, and the result fetched to the
         host.  ``fetch=False`` returns the outputs on the device.
+        ``rows`` counts the resident mask rows the step gathers.
 
         Traced, the call is three spans with attr ``step``:
         ``device.call`` (conversion and the asynchronous dispatch),
         ``device.wait`` (``block_until_ready``) and ``device.fetch`` (the
         copy to the host).  Untraced, nothing waits but the fetch."""
         self.stats.device_calls += 1
+        self.stats.gathered_bytes += rows * self._row_nbytes
         traced = _trace.current_tracer().enabled
         with _trace.span("device.call") as sp:
             sp.set(step=step)
@@ -624,7 +643,8 @@ class DeviceBackend(_KthValueMixin, ExecBackend):
         rois_q, lvs, uvs = spec_arrays(
             [(ctx.resolve_rois(t.roi, pos), t.lv, t.uv) for t in terms])
         counts = self._step(*self._multi(), self._masks, np.asarray(pos),
-                            rois_q, lvs, uvs, row_shape=self._row_shape)
+                            rois_q, lvs, uvs, rows=len(pos),
+                            row_shape=self._row_shape)
         return {t: counts[i].astype(np.float64)
                 for i, t in enumerate(terms)}
 
@@ -632,7 +652,8 @@ class DeviceBackend(_KthValueMixin, ExecBackend):
                             decided, lb):
         return self._step("_device_fused_verify", _device_fused_verify,
                           self._masks, np.asarray(pos), rois_q, lvs, uvs,
-                          decided, lb, row_shape=self._row_shape)
+                          decided, lb, rows=len(pos),
+                          row_shape=self._row_shape)
 
     def topk_candidates(self, lb, ub, k, desc, definite, possible):
         if k <= 0 or int(np.count_nonzero(definite)) < k:
@@ -653,28 +674,30 @@ class DeviceBackend(_KthValueMixin, ExecBackend):
             counts = self._step(
                 "_device_group_counts_packed", _device_group_counts_packed,
                 self._masks, flat, rois, np.asarray(node.thresh, np.float32),
-                pick=pick, s=int(s), row_shape=self._row_shape)
+                pick=pick, rows=len(flat), s=int(s),
+                row_shape=self._row_shape)
         else:
             counts = self._step(
                 "_device_group_counts", _device_group_counts, self._masks,
                 flat, rois, np.asarray(node.thresh, self._masks.dtype),
-                pick=pick, s=int(s), row_shape=self._row_shape)
+                pick=pick, rows=len(flat), s=int(s),
+                row_shape=self._row_shape)
         return counts.astype(np.float64)
 
     def fused_counts(self, store, positions, specs):
         rois_q, lvs, uvs = spec_arrays(specs)
         return self._step(*self._multi(), self._masks,
                           np.asarray(positions), rois_q, lvs, uvs,
-                          row_shape=self._row_shape)
+                          rows=len(positions), row_shape=self._row_shape)
 
     def fused_pair_counts(self, store, pos_a, pos_b, specs):
         # Both roles are resident (the store's one HBM mask array); gather
         # each role ONCE and answer every descriptor against the gathered
         # batch — zero metered bytes, 2 gathers regardless of Q.
         a = self._step("gather", gather, self._masks, np.asarray(pos_a),
-                       fetch=False, row_shape=self._row_shape)
+                       fetch=False, rows=len(pos_a), row_shape=self._row_shape)
         b = self._step("gather", gather, self._masks, np.asarray(pos_b),
-                       fetch=False, row_shape=self._row_shape)
+                       fetch=False, rows=len(pos_b), row_shape=self._row_shape)
         if self._packed:
             step, kernel, tdt = ("pair_counts_packed",
                                  kops.pair_counts_packed, np.float32)

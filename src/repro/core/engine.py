@@ -61,6 +61,8 @@ class ExecStats:
     bytes_loaded: int = 0             # store bytes metered for this run
     bytes_saved: int = 0              # served from the shared-load cache
     chi_bytes: int = 0                # index bytes the bounds passes touched
+    resident_bytes: int = 0           # resident mask rows the device
+                                      # verification steps gathered, bytes
     bound_time_s: float = 0.0
     verify_time_s: float = 0.0
 
@@ -394,6 +396,7 @@ class _VerifyRun:
         cache = self.store.cache_stats
         io0 = self.store.io.bytes_read
         saved0, hits0 = cache.bytes_saved, cache.hits
+        gathered0 = self.backend.gathered_bytes
         t0 = time.perf_counter()
         with _trace.span("verify.round") as sp:
             self.apply_exact(batch, self.exact_values(batch))
@@ -404,6 +407,7 @@ class _VerifyRun:
         self.stats.verify_time_s += time.perf_counter() - t0
         self.stats.bytes_loaded += self.store.io.bytes_read - io0
         self.stats.bytes_saved += cache.bytes_saved - saved0
+        self.stats.resident_bytes += self.backend.gathered_bytes - gathered0
 
     def _drain(self) -> None:
         while not self.finished():

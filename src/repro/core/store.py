@@ -55,6 +55,10 @@ EBS_THROUGHPUT_BYTES_S = 125 * 1024 * 1024
 EBS_IOPS = 3000.0
 EBS_IO_CHUNK = 256 * 1024  # gp3 accounting chunk for large sequential reads
 
+# Lane width of a TPU vreg: float masks are held on the device in rows of
+# 128 values (``MaskStore.device_row_shape``).
+LANES = 128
+
 # Shared-load cache default bound (satellite: the cache must not grow
 # without limit across a long-lived service).
 DEFAULT_CACHE_BYTES = 256 * 1024 * 1024
@@ -823,21 +827,32 @@ class MaskStore:
                 self._resident = out
         return self._resident
 
-    def _rows(self, masks) -> np.ndarray:
-        """Stored masks ``(n,) + row_shape`` as 2-D rows ``(n, H·W')``: a
-        host reshape, so no bytes move."""
+    @property
+    def device_row_shape(self) -> tuple:
+        """Shape of one mask in :meth:`device_masks`: the packed words as
+        one row ``(H·W',)``; float pixels in lanes ``(H·W/128, 128)`` when
+        ``H·W`` is a multiple of 128, else one row ``(H·W,)``."""
         h, w = self.row_shape
-        return np.asarray(masks, self.row_dtype).reshape(len(masks), h * w)
+        if not self.packed and (h * w) % LANES == 0:
+            return (h * w // LANES, LANES)
+        return (h * w,)
+
+    def _rows(self, masks) -> np.ndarray:
+        """Stored masks ``(n,) + row_shape`` in the device tier's form
+        ``(n,) + device_row_shape``: a host reshape, so no bytes move."""
+        return np.asarray(masks, self.row_dtype).reshape(
+            (len(masks),) + self.device_row_shape)
 
     def device_masks(self):
         """:meth:`resident_masks` pinned in device memory (jnp, cached) —
-        the HBM-resident tier the device backend verifies against — held
-        as 2-D rows ``(n, H·W')``: ``row_shape`` flattened, so one mask is
-        one row (DESIGN.md §7).  A 3-D array gets a compact TPU layout
-        whose row gather relayouts the whole store first; whole rows of
-        the 2-D array gather as they lie.  Once materialized, mutations
-        maintain it incrementally: appends ``device_put`` only the new
-        rows, updates scatter the changed rows, deletes gather the
+        the HBM-resident tier the device backend verifies against — one
+        mask per leading index, ``(n,) + device_row_shape`` (DESIGN.md §7).
+        A 3-D ``(n,) + row_shape`` array gets a compact TPU layout whose row
+        gather relayouts the whole store first, and a 2-D float row of
+        hundreds of KB is gathered through column slabs of the whole store;
+        packed rows and float lanes gather as they lie.  Once materialized,
+        mutations maintain it incrementally: appends ``device_put`` only
+        the new rows, updates scatter the changed rows, deletes gather the
         survivors."""
         if self._device_masks is None:
             self._device_masks = jnp.asarray(self._rows(self.resident_masks()))

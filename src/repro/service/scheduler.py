@@ -206,6 +206,7 @@ class FusedScheduler:
             [j.ctx.positions[b] for j, b in pairs]))
         io0 = store.io.bytes_read
         saved0 = store.cache_stats.bytes_saved
+        gathered0 = self.backend.gathered_bytes
         t0 = time.perf_counter()
 
         with _trace.span("scheduler.fused_pass") as sp:
@@ -245,25 +246,30 @@ class FusedScheduler:
         # in SchedulerStats.fused_bytes_loaded / fused_time_s.
         self._account(pairs, store.io.bytes_read - io0,
                       store.cache_stats.bytes_saved - saved0,
+                      self.backend.gathered_bytes - gathered0,
                       time.perf_counter() - t0)
 
     def _account(self, pairs, bytes_delta: int, saved_delta: int,
-                 elapsed: float) -> None:
-        """Attribute one fused round's *metered* bytes and wall time to the
-        participating runs, proportional to batch size.  The byte
-        apportionment is exact (largest remainder), so the sum of per-run
-        ``bytes_loaded`` equals the store's metered delta — never the
-        truncation drift of per-job ``int(delta * share)``.  Bytes the
-        shared-load cache served count once globally (the store meters only
-        misses) and are attributed per run as ``bytes_saved``."""
+                 gathered_delta: int, elapsed: float) -> None:
+        """Attribute one fused round's *metered* bytes, the resident bytes
+        the device gathered, and wall time to the participating runs,
+        proportional to batch size.  The byte apportionment is exact
+        (largest remainder), so the sum of per-run ``bytes_loaded`` equals
+        the store's metered delta — never the truncation drift of per-job
+        ``int(delta * share)`` — and likewise ``resident_bytes`` the
+        backend's ``gathered_bytes`` delta.  Bytes the shared-load cache
+        served count once globally (the store meters only misses) and are
+        attributed per run as ``bytes_saved``."""
         self.stats.fused_bytes_loaded += bytes_delta
         self.stats.fused_time_s += elapsed
         weights = [len(b) for _, b in pairs]
-        for (job, batch), share_bytes, share_saved in zip(
+        for (job, batch), share_bytes, share_saved, share_gathered in zip(
                 pairs, _apportion(bytes_delta, weights),
-                _apportion(saved_delta, weights)):
+                _apportion(saved_delta, weights),
+                _apportion(gathered_delta, weights)):
             job.stats.bytes_loaded += share_bytes
             job.stats.bytes_saved += share_saved
+            job.stats.resident_bytes += share_gathered
             job.stats.verify_time_s += \
                 elapsed * len(batch) / max(sum(weights), 1)
 
@@ -287,6 +293,7 @@ class FusedScheduler:
         u_pb = (all_keys & 0xffffffff).astype(np.int64)
         io0 = store.io.bytes_read
         saved0 = store.cache_stats.bytes_saved
+        gathered0 = self.backend.gathered_bytes
         t0 = time.perf_counter()
 
         with _trace.span("scheduler.pair_pass") as sp:
@@ -323,4 +330,5 @@ class FusedScheduler:
 
         self._account(pairs, store.io.bytes_read - io0,
                       store.cache_stats.bytes_saved - saved0,
+                      self.backend.gathered_bytes - gathered0,
                       time.perf_counter() - t0)

@@ -24,9 +24,9 @@ TOPK_SQL = ("SELECT mask_id FROM MasksDatabaseView ORDER BY "
             "CP(mask, full_img, (0.2, 0.6)) DESC LIMIT {k};")
 
 
-def _data(n, seed=0, id_base=0):
-    boxes = object_boxes(n, H, W, seed=seed + 1)
-    masks, _ = saliency_masks(n, H, W, seed=seed, attacked_fraction=0.3,
+def _data(n, seed=0, id_base=0, h=H, w=W):
+    boxes = object_boxes(n, h, w, seed=seed + 1)
+    masks, _ = saliency_masks(n, h, w, seed=seed, attacked_fraction=0.3,
                               boxes=boxes)
     meta = np.zeros(n, MASK_META_DTYPE)
     meta["mask_id"] = id_base + np.arange(n)
@@ -486,8 +486,8 @@ def test_lru_cache_concurrent_access():
 # ---------------------------------------------------------------------------
 
 
-def _binary_data(n, seed=0, id_base=0):
-    masks, meta = _data(n, seed=seed, id_base=id_base)
+def _binary_data(n, seed=0, id_base=0, h=H, w=W):
+    masks, meta = _data(n, seed=seed, id_base=id_base, h=h, w=w)
     return (masks > 0.5).astype(np.float32), meta
 
 
@@ -583,19 +583,27 @@ def test_stale_run_error_surfaces_as_conflict():
         run_plan(store, plan, backend=backend)
 
 
-@pytest.mark.parametrize("packed", [False, True], ids=["float", "packed"])
-def test_device_rows_follow_append_update_delete(packed):
-    """The device tier holds one mask per 2-D row, ``(n, H·W')``.  After
-    each append, update and delete it equals the host copy row for row,
-    and the device backend answers a plan through each of its gathering
-    steps (fused CP counts, the megakernel, MASK_AGG groups, the pair
-    pass) exactly as the host backend does."""
+@pytest.mark.parametrize("packed,size,row", [
+    (False, 32, (8, 128)), (True, 32, (32,)), (False, 36, (36 * 36,))],
+    ids=["float", "packed", "float_rows"])
+def test_device_rows_follow_append_update_delete(packed, size, row):
+    """The device tier holds one mask per leading index: packed words as
+    2-D rows ``(n, H·W')``, float pixels in lanes ``(n, H·W/128, 128)``
+    where ``H·W`` is a multiple of 128 (32×32) and as 2-D rows where it is
+    not (36×36).  After each append, update and delete it equals the host
+    copy mask for mask, and the device backend answers a plan through each
+    of its gathering steps (fused CP counts, the megakernel, MASK_AGG
+    groups, the pair pass) exactly as the host backend does."""
     from repro.core.backend import get_backend
     from repro.core.exprs import AggCP, pair_iou
 
-    data = _binary_data if packed else _data
+    def data(n, seed=0, id_base=0):
+        make = _binary_data if packed else _data
+        return make(n, seed=seed, id_base=id_base, h=size, w=size)
+
+    cfg = CHIConfig(grid=4, num_bins=8, height=size, width=size)
     masks, meta = data(B)
-    store = MaskStore.create_memory(masks, meta, CFG, packed=packed)
+    store = MaskStore.create_memory(masks, meta, cfg, packed=packed)
     get_backend(store, "device")          # the resident upload, epoch 0
     plans = [
         LogicalPlan(order_by=CP(None, 0.5, 1.5), k=5),
@@ -610,9 +618,9 @@ def test_device_rows_follow_append_update_delete(packed):
     def check():
         n = len(store)
         rows = np.asarray(store.device_masks())
-        assert rows.shape == (n, store.row_shape[0] * store.row_shape[1])
+        assert rows.shape == (n,) + row
         np.testing.assert_array_equal(
-            rows, store.resident_masks().reshape(n, -1))
+            rows.reshape((n,) + store.row_shape), store.resident_masks())
         verified = 0
         for plan in plans:
             (want, want_scores), want_stats = run_plan(store, plan,

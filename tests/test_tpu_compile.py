@@ -2,7 +2,8 @@
 attached) v5e chip, at the deployment's shapes: B = 256 float32 224×224
 masks, packed rows of 7 words, Q = 4 descriptors, S = 2 mask types, CHI
 grid 16 × 16 bins.  Also one sharded kernel step on ``v5e:2x2``, and the
-device backend's gathering steps over resident stores of 2-D mask rows.
+device backend's gathering steps over resident stores of 2-D mask rows
+and of float masks in lanes of 128.
 
 Nothing runs: a compile that passes here is not a chip run.  It catches
 what the chip's compiler refuses (block shapes off the (8, 128) tiling,
@@ -154,8 +155,9 @@ def test_device_gather_step_reads_resident_rows_without_relayout(
     a 3-D ``(n, H, W')`` store the chip's compact layout makes XLA copy
     the whole store before the gather (0.73 GB of temporaries for the
     packed steps, 3.9 GB for the float ones).  Packed rows gather as they
-    lie; float rows of 784 KB still pass through column slabs of the whole
-    store, but never more than its own size beside the batch."""
+    lie; float 2-D rows of 784 KB still pass through column slabs of the
+    whole store, but never more than its own size beside the batch (the
+    store holds such floats in lanes instead: the lane-row test below)."""
     from repro.core import backend as be
 
     n, row_shape, dtype = GATHER_STEPS[name]
@@ -186,6 +188,40 @@ def test_device_gather_step_reads_resident_rows_without_relayout(
         assert temp < 0.1e9
     else:
         assert temp <= (n + B) * row * np.dtype(dtype).itemsize
+
+
+# The float tier at the paper's 448 × 448: 16,000 masks held in lanes,
+# (n, 1568, 128), 12.8 GB of the chip's 16 (MaskStore.device_row_shape).
+LANE_STORE = (16000, 448 * 448 // 128, 128)
+LANE_STEPS = {"_device_multi_counts": B, "_device_group_counts": 2 * B,
+              "gather": B}
+
+
+@pytest.mark.parametrize("name", sorted(LANE_STEPS))
+def test_float_step_gathers_a_batch_from_lane_rows(name, one_chip,
+                                                   tpu_dispatch):
+    """Over float rows in lanes of 128 each step's temporaries are the
+    size of its batch (256 rows, 512 for the grouped step; 0.2–0.4 GB a
+    copy), under 1 GB.  Over 2-D rows ``(n, 200704)`` XLA passes the whole
+    store through column slabs: as many temporaries as the store holds
+    (7.2 GB at 9,000 masks), and no fit at 14,000."""
+    from repro.core import backend as be
+
+    b = LANE_STEPS[name]
+
+    def s(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    rows, pos = s(LANE_STORE, jnp.float32), s((b,))
+    vals, thresh = s((Q,), jnp.float32), s((), jnp.float32)
+    args = {
+        "_device_multi_counts": (rows, pos, s((Q, b, 4)), vals, vals),
+        "_device_group_counts": (rows, pos, s((b // S, 4)), thresh),
+        "gather": (rows, pos),
+    }[name]
+    static = {"s": S} if "group" in name else {}
+    compiled = getattr(be, name).lower(*args, row_shape=(448, 448),
+                                       **static).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e9
 
 
 def test_device_bounds_step_gathers_chi_rows_without_relayout(one_chip):
