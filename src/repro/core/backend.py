@@ -377,7 +377,10 @@ def _batch(rows, pos, row_shape):
     lanes of 128): whole masks gather as they lie, and only the batch is
     reshaped.  The same gather from a 3-D ``(n, H, W')`` array would first
     relayout the whole store, and from 2-D float rows it passes the whole
-    store through column slabs (DESIGN.md §7).
+    store through column slabs (DESIGN.md §7).  The float verification
+    steps over lane rows do not come here: their kernels read the rows in
+    place (``_in_lanes``); the packed steps, the pair pass's ``gather`` and
+    float 2-D rows do.
 
     The barrier keeps XLA from fusing the batch's reshape into the Pallas
     call's operand: fused, a batch of 104 rows took the TPU compiler 7 s
@@ -386,10 +389,20 @@ def _batch(rows, pos, row_shape):
         rows[pos].reshape(pos.shape + row_shape))
 
 
+def _in_lanes(rows) -> bool:
+    """Whether resident float rows lie in lanes of 128, ``(n, H·W/128,
+    128)``, so a verification kernel can read each row where it lies."""
+    return rows.ndim == 3 and rows.shape[-1] == 128
+
+
 @functools.partial(jax.jit, static_argnames=("row_shape",))
 def _device_multi_counts(masks, pos, rois_q, lvs, uvs, row_shape):
-    """Gather a verification batch from the resident mask rows and answer
-    Q CP descriptors in one fused kernel pass."""
+    """Answer Q CP descriptors over the resident float rows at ``pos`` in
+    one fused kernel pass: read in place from lane rows, else over a
+    gathered batch."""
+    if _in_lanes(masks):
+        return kops.cp_count_multi_inplace(masks, pos, rois_q, lvs, uvs,
+                                           row_shape=row_shape)
     return kops.cp_count_multi(_batch(masks, pos, row_shape), rois_q, lvs,
                                uvs)
 
@@ -402,6 +415,9 @@ def _device_kth_index(pes, definite, k):
 
 @functools.partial(jax.jit, static_argnames=("s", "row_shape"))
 def _device_group_counts(masks, flat_pos, rois, thresh, s, row_shape):
+    if _in_lanes(masks):
+        return kops.mask_agg_counts_inplace(masks, flat_pos, rois, thresh,
+                                            s=s, row_shape=row_shape)
     grp = _batch(masks, flat_pos, row_shape)
     return kops.mask_agg_counts(grp.reshape((-1, s) + row_shape), rois,
                                 thresh)
@@ -481,14 +497,16 @@ class BackendStats:
     """Host↔device traffic of the device backend (monotonic, always on;
     ``/metrics`` as ``masksearch_backend_*``): bytes of step arguments
     converted host→device, bytes fetched device→host, calls into jitted
-    steps or eager device ops, output arrays fetched, and bytes of
-    resident mask rows the verification steps gathered (rows × one
-    stored row)."""
+    steps or eager device ops, output arrays fetched, bytes of resident
+    mask rows the verification steps read (rows × one stored row,
+    ``gathered_bytes``), and of those rows the ones a kernel read in place
+    from the resident store, with no gathered batch (``inplace_rows``)."""
     h2d_bytes: int = 0
     d2h_bytes: int = 0
     device_calls: int = 0
     fetches: int = 0
     gathered_bytes: int = 0
+    inplace_rows: int = 0
 
 
 class DeviceBackend(_KthValueMixin, ExecBackend):
@@ -510,6 +528,8 @@ class DeviceBackend(_KthValueMixin, ExecBackend):
         self._masks = store.device_masks()          # one mask per index
         self._row_shape = store.row_shape           # what a kernel sees
         self._row_nbytes = store.row_nbytes
+        # float lane rows: the CP and grouped kernels read them in place
+        self._inplace = not self._packed and _in_lanes(self._masks)
         self._tables = store.chi_table
         self._epoch = getattr(store, "epoch", 0)
         self._rb = jnp.asarray(self.cfg.row_bounds, jnp.int32)
@@ -552,12 +572,14 @@ class DeviceBackend(_KthValueMixin, ExecBackend):
         return self.stats.gathered_bytes
 
     def _step(self, step: str, fn, *args, pick: int | None = None,
-              fetch: bool = True, rows: int = 0, **static):
+              fetch: bool = True, rows: int = 0, inplace: bool = False,
+              **static):
         """One call into the device: host ``args`` converted, ``fn``
         dispatched (``static`` keywords passed through), output ``pick``
         of a tuple chosen on the device, and the result fetched to the
         host.  ``fetch=False`` returns the outputs on the device.
-        ``rows`` counts the resident mask rows the step gathers.
+        ``rows`` counts the resident mask rows the step reads, and
+        ``inplace`` says that its kernel reads them where they lie.
 
         Traced, the call is three spans with attr ``step``:
         ``device.call`` (conversion and the asynchronous dispatch),
@@ -565,6 +587,8 @@ class DeviceBackend(_KthValueMixin, ExecBackend):
         copy to the host).  Untraced, nothing waits but the fetch."""
         self.stats.device_calls += 1
         self.stats.gathered_bytes += rows * self._row_nbytes
+        if inplace:
+            self.stats.inplace_rows += rows
         traced = _trace.current_tracer().enabled
         with _trace.span("device.call") as sp:
             sp.set(step=step)
@@ -644,6 +668,7 @@ class DeviceBackend(_KthValueMixin, ExecBackend):
             [(ctx.resolve_rois(t.roi, pos), t.lv, t.uv) for t in terms])
         counts = self._step(*self._multi(), self._masks, np.asarray(pos),
                             rois_q, lvs, uvs, rows=len(pos),
+                            inplace=self._inplace,
                             row_shape=self._row_shape)
         return {t: counts[i].astype(np.float64)
                 for i, t in enumerate(terms)}
@@ -680,7 +705,7 @@ class DeviceBackend(_KthValueMixin, ExecBackend):
             counts = self._step(
                 "_device_group_counts", _device_group_counts, self._masks,
                 flat, rois, np.asarray(node.thresh, self._masks.dtype),
-                pick=pick, rows=len(flat), s=int(s),
+                pick=pick, rows=len(flat), inplace=self._inplace, s=int(s),
                 row_shape=self._row_shape)
         return counts.astype(np.float64)
 
@@ -688,7 +713,8 @@ class DeviceBackend(_KthValueMixin, ExecBackend):
         rois_q, lvs, uvs = spec_arrays(specs)
         return self._step(*self._multi(), self._masks,
                           np.asarray(positions), rois_q, lvs, uvs,
-                          rows=len(positions), row_shape=self._row_shape)
+                          rows=len(positions), inplace=self._inplace,
+                          row_shape=self._row_shape)
 
     def fused_pair_counts(self, store, pos_a, pos_b, specs):
         # Both roles are resident (the store's one HBM mask array); gather

@@ -175,3 +175,217 @@ def cp_count_multi_pallas(masks: jax.Array, rois: jax.Array,
         return out.reshape(q, nb)
 
     return _over_rows(call, b, 5 * q)
+
+
+# -- reading the resident lane rows in place ---------------------------------
+#
+# The device tier holds float masks of ``H·W`` pixels in lanes of 128,
+# ``f32[n, L, 128]`` with ``L = H·W/128`` (``MaskStore.device_row_shape``).
+# The in-place kernels take that whole store and the batch's positions: the
+# positions are a scalar-prefetch operand, and each input block's index map
+# picks store row ``pos[i]``, so the pipeline DMAs every row from HBM into
+# VMEM once and no batch is gathered, copied or relaid out beforehand.  A
+# block is the whole row where it fits the tile budget (``lane_geometry``),
+# else a tile of ``lb`` lane rows on a second, sequential grid axis, across
+# which the counts accumulate.
+#
+# Pixel ``(y, x)`` lies at flat index ``f = 128·r + l`` (lane row ``r``,
+# lane ``l``), so a row of width W may straddle lane rows.  The ROI test is
+# exact on ``f``: ``r0 ≤ y < r1`` is ``r0·W ≤ f < r1·W`` (the corners are
+# clipped to ``[0, H]`` first, which changes no answer), and ``x = f mod W``
+# is computed in the kernel, exactly (``image_column``).
+
+LANES = 128
+
+
+def lane_geometry(rows: jax.Array, width: int,
+                  members: int = 1) -> tuple[int, int, int]:
+    """``(H, lb, chunk)`` of a lane-row store of masks ``width`` wide, read
+    ``members`` rows at a time: ``lb`` lane rows per block, the whole row
+    where ``members`` blocks fit the tile budget (picked as ``_pick_bh``
+    picks row tiles), and ``chunk`` lane rows per inner step, the largest
+    multiple of 8 up to 64 that divides ``lb`` (whole sublane tiles, a few
+    vregs per value), else the whole block."""
+    l = rows.shape[1]
+    h = l * LANES // width
+    if h * width >= 2**24:
+        raise ValueError(f"{h}x{width} masks: image_column is exact below "
+                         f"2**24 pixels")
+    lb = _pick_bh(l, LANES, rows.dtype.itemsize,
+                  budget_bytes=2 * 1024 * 1024 // members)
+    chunk = next((c for c in range(64, 0, -8) if lb % c == 0), lb)
+    return h, lb, chunk
+
+
+def flat_rois(rois: jax.Array, h: int, w: int) -> jax.Array:
+    """ROI corners ``(…, 4)`` as ``(r0, c0, r1, c1)`` → ``(r0·W, c0, r1·W,
+    c1)`` with the rows clipped to ``[0, H]``: the row bounds as flat pixel
+    indices, exact for every ``(y, x)`` of an ``H × W`` mask."""
+    rois = rois.astype(jnp.int32)
+    rows = jnp.clip(rois[..., 0::2], 0, h) * w
+    return jnp.stack([rows[..., 0], rois[..., 1], rows[..., 1],
+                      rois[..., 3]], axis=-1)
+
+
+def store_rows(pos: jax.Array, n: int) -> jax.Array:
+    """Positions as int32 store rows, clamped to ``[0, n)`` as a gather
+    ``rows[pos]`` clamps them, so no DMA reads past the store."""
+    return jnp.clip(pos.astype(jnp.int32), 0, n - 1)
+
+
+def lane_tile(rows_ref, start, chunk: int):
+    """Lane rows ``[start, start + chunk)`` of a ``(1, lb, 128)`` block."""
+    return rows_ref[0, pl.ds(start, chunk), :].astype(jnp.float32)
+
+
+def flat_index(chunk: int):
+    """``f`` of a chunk's pixels relative to its first lane row."""
+    return (jax.lax.broadcasted_iota(jnp.int32, (chunk, LANES), 0) * LANES
+            + jax.lax.broadcasted_iota(jnp.int32, (chunk, LANES), 1))
+
+
+def image_column(f, w: int):
+    """``x = f mod W`` of flat pixel indices ``0 ≤ f < 2**24``, exactly: a
+    float32 estimate of ``f // W``, off by at most one, corrected in
+    integers."""
+    x = f - (f.astype(jnp.float32) * (1.0 / w)).astype(jnp.int32) * w
+    x = jnp.where(x < 0, x + w, x)
+    return jnp.where(x >= w, x - w, x)
+
+
+def roi_inside_flat(corners, f, x):
+    """The ROI predicate from flat ``corners`` (``flat_rois``) over a
+    chunk's flat indices ``f`` and image columns ``x``."""
+    f0, c0, f1, c1 = corners
+    return (f >= f0) & (f < f1) & (x >= c0) & (x < c1)
+
+
+def fold(v: jax.Array) -> jax.Array:
+    """Int32 partial sums of a ``(chunk, 128)`` tile, kept as one
+    ``(8, 128)`` vreg where the chunk is whole sublane tiles."""
+    c = v.shape[0]
+    if c % 8:
+        return v
+    return v.reshape(c // 8, 8, LANES).sum(axis=0)
+
+
+def over_chunks(body, span, n: int, chunk: int, init):
+    """``body(start, carry)`` over the chunks ``[lo, hi) = span`` of a block
+    of ``n`` chunks of ``chunk`` lane rows: a loop on the chip, a plain
+    call on chunk 0 where the block is one chunk (its start then stays a
+    static 0; the body's own tests keep the count exact)."""
+    if n == 1:
+        return body(0, init)
+    return jax.lax.fori_loop(
+        span[0], span[1],
+        lambda c, acc: body(pl.multiple_of(c * chunk, chunk), acc), init)
+
+
+def roi_span(corners, chunk: int):
+    """The chunks ``[lo, hi)`` of a row that hold pixels of the ROIs'
+    image rows (flat ``corners``, one per ROI): the others count 0."""
+    step = chunk * LANES
+    lo = functools.reduce(jnp.minimum, [c[0] for c in corners])
+    hi = functools.reduce(jnp.maximum, [c[2] for c in corners])
+    return lo // step, (hi + step - 1) // step
+
+
+def whole_image(corners, h: int, w: int):
+    """Whether every ROI covers the whole ``h × w`` image, so the count
+    needs no ROI test."""
+    return functools.reduce(jnp.logical_and, [
+        (c[0] <= 0) & (c[2] >= h * w) & (c[1] <= 0) & (c[3] >= w)
+        for c in corners])
+
+
+def roi_or_whole(corners, body, *, h: int, w: int, chunk: int, n: int,
+                 first, init):
+    """``body(roi)`` counted over a block of ``n`` chunks whose first is
+    chunk ``first`` of the row: without an ROI test where every ROI covers
+    the whole image, else over the block's chunks of the ROIs' rows."""
+    lo, hi = roi_span(corners, chunk)
+    span = jnp.clip(lo - first, 0, n), jnp.clip(hi - first, 0, n)
+    return jax.lax.cond(
+        whole_image(corners, h, w),
+        lambda: over_chunks(body(False), (0, n), n, chunk, init),
+        lambda: over_chunks(body(True), span, n, chunk, init))
+
+
+def _cp_inplace_body(rows_ref, bounds_ref, corners, *, q: int, chunk: int,
+                     w: int, row0):
+    """The chunk body of the in-place CP kernel over a block whose first
+    lane row is ``row0`` of the mask, with or without the ROI test: one
+    tile load answers all Q descriptors."""
+    base = flat_index(chunk)
+
+    def body(roi: bool):
+        def step(start, accs):
+            m = lane_tile(rows_ref, start, chunk)
+            if roi:
+                f = base + (row0 + start) * LANES
+                x = image_column(f, w)
+            out = []
+            for qi in range(q):
+                hit = (m >= bounds_ref[qi]) & (m < bounds_ref[q + qi])
+                if roi:
+                    hit = hit & roi_inside_flat(corners[qi], f, x)
+                out.append(accs[qi] + fold(hit.astype(jnp.int32)))
+            return tuple(out)
+        return step
+
+    return body
+
+
+def _cp_inplace_kernel(pos_ref, rois_ref, bounds_ref, rows_ref, out_ref, *,
+                       q: int, nb: int, lb: int, chunk: int, h: int, w: int):
+    del pos_ref                                    # read by the index map
+    i, tile = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(tile == 0)
+    def _init():
+        for qi in range(q):
+            out_ref[qi * nb + i] = 0
+
+    corners = [[rois_ref[4 * (qi * nb + i) + k] for k in range(4)]
+               for qi in range(q)]
+    body = _cp_inplace_body(rows_ref, bounds_ref, corners, q=q, chunk=chunk,
+                            w=w, row0=tile * lb)
+    zero = jnp.zeros(fold(flat_index(chunk)).shape, jnp.int32)
+    n = lb // chunk
+    accs = roi_or_whole(corners, body, h=h, w=w, chunk=chunk, n=n,
+                        first=tile * n, init=tuple(zero for _ in range(q)))
+    for qi in range(q):
+        out_ref[qi * nb + i] += jnp.sum(accs[qi])
+
+
+def cp_count_multi_inplace_pallas(rows: jax.Array, pos: jax.Array,
+                                  rois: jax.Array, lvs: jax.Array,
+                                  uvs: jax.Array, *, width: int,
+                                  interpret: bool = False) -> jax.Array:
+    """Store ``(n, L, 128)``, positions ``(B,)``, ``(Q, B, 4)``, ``(Q,)``,
+    ``(Q,)`` → ``(Q, B)`` int32: ``cp_count_multi`` over the masks of width
+    ``width`` at ``pos``, each read once where it lies in the store."""
+    h, lb, chunk = lane_geometry(rows, width)
+    b, q = pos.shape[0], rois.shape[0]
+    bounds = value_scalars(jnp.concatenate([lvs, uvs]), rows.dtype)
+    rois = flat_rois(rois, h, width)
+    pos = store_rows(pos, rows.shape[0])
+
+    def call(s, e):
+        nb = e - s
+        kernel = functools.partial(_cp_inplace_kernel, q=q, nb=nb, lb=lb,
+                                   chunk=chunk, h=h, w=width)
+        out = pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=3,
+                grid=(nb, rows.shape[1] // lb),
+                in_specs=[pl.BlockSpec((1, lb, LANES),
+                                       lambda i, j, p, r, v: (p[i], j, 0))],
+                out_specs=SMEM_OUT),
+            out_shape=jax.ShapeDtypeStruct((q * nb,), jnp.int32),
+            interpret=interpret,
+        )(pos[s:e], rois[:, s:e].reshape(-1), bounds, rows)
+        return out.reshape(q, nb)
+
+    return _over_rows(call, b, 5 * q + 1)

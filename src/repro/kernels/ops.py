@@ -29,8 +29,9 @@ from ..obs.metrics import REGISTRY as _REG
 from ..obs.metrics import watch_compiles
 from . import popcount, ref
 from .chi_build import chi_cell_hist_pallas
-from .cp_count import cp_count_multi_pallas, cp_count_pallas
-from .mask_agg import mask_agg_counts_pallas
+from .cp_count import (cp_count_multi_inplace_pallas, cp_count_multi_pallas,
+                       cp_count_pallas)
+from .mask_agg import mask_agg_counts_inplace_pallas, mask_agg_counts_pallas
 from .pair_count import pair_counts_pallas
 
 _FORCE_INTERPRET = os.environ.get("REPRO_FORCE_PALLAS_INTERPRET", "") == "1"
@@ -129,6 +130,41 @@ def mask_agg_counts(group_masks, rois, thresh, *,
     return ref.mask_agg_counts_ref(group_masks, rois, thresh)
 
 
+@functools.partial(jax.jit,
+                   static_argnames=("row_shape", "use_pallas", "interpret"))
+def cp_count_multi_inplace(rows, pos, rois, lvs, uvs, *, row_shape,
+                           use_pallas: bool | None = None,
+                           interpret: bool = False):
+    """Multi-query CP over resident lane rows — store (n, L, 128) of
+    ``row_shape`` (H, W) masks, positions (B,), (Q,B,4), (Q,), (Q,) →
+    (Q,B) int32; the kernel reads each row where it lies."""
+    pallas, interpret = _dispatch(use_pallas, interpret)
+    if pallas:
+        return cp_count_multi_inplace_pallas(rows, pos, rois, lvs, uvs,
+                                             width=row_shape[1],
+                                             interpret=interpret)
+    return ref.cp_count_multi_ref(rows[pos].reshape(pos.shape + row_shape),
+                                  rois, lvs, uvs)
+
+
+@functools.partial(jax.jit, static_argnames=("s", "row_shape", "use_pallas",
+                                             "interpret"))
+def mask_agg_counts_inplace(rows, pos, rois, thresh, *, s, row_shape,
+                            use_pallas: bool | None = None,
+                            interpret: bool = False):
+    """Fused MASK_AGG counts over resident lane rows — store (n, L, 128)
+    of ``row_shape`` (H, W) masks, group-major member positions (N·S,),
+    (N,4) → (inter, union) int32; the kernel reads each row where it
+    lies."""
+    pallas, interpret = _dispatch(use_pallas, interpret)
+    if pallas:
+        return mask_agg_counts_inplace_pallas(rows, pos, rois, thresh, s=s,
+                                              width=row_shape[1],
+                                              interpret=interpret)
+    grp = rows[pos].reshape((-1, s) + row_shape)
+    return ref.mask_agg_counts_ref(grp, rois, thresh)
+
+
 @functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))
 def pair_counts(masks_a, masks_b, rois, ta, tb, *,
                 use_pallas: bool | None = None, interpret: bool = False):
@@ -216,6 +252,10 @@ cp_count = _instrument("cp_count", cp_count)
 cp_count_multi = _instrument("cp_count_multi", cp_count_multi)
 chi_cell_hist = _instrument("chi_cell_hist", chi_cell_hist)
 mask_agg_counts = _instrument("mask_agg_counts", mask_agg_counts)
+cp_count_multi_inplace = _instrument("cp_count_multi_inplace",
+                                     cp_count_multi_inplace)
+mask_agg_counts_inplace = _instrument("mask_agg_counts_inplace",
+                                      mask_agg_counts_inplace)
 pair_counts = _instrument("pair_counts", pair_counts)
 cp_count_packed = _instrument("cp_count_packed", cp_count_packed)
 cp_count_multi_packed = _instrument("cp_count_multi_packed",

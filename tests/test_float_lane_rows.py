@@ -85,18 +85,24 @@ def test_device_answers_equal_host_over_float_rows(size, row):
 
 def test_resident_bytes_sum_to_the_gathered_bytes():
     """Each answer's ``resident_bytes`` is its share of the rows the
-    device steps gathered: over a serial run of queries they sum to the
+    device steps read: over a serial run of queries they sum to the
     change of ``BackendStats.gathered_bytes``, a whole number of stored
-    rows, and the host backend gathers none."""
+    rows, and the host backend reads none.  Of those rows, the CP and
+    grouped verification kernels read every one in place from the lane
+    rows (``inplace_rows``); only the pair pass (the IoU query) gathers."""
     store, boxes = _store(32)
     host = MaskSearchService(store, provided_rois=boxes, verify_batch=8)
     device = MaskSearchService(store, provided_rois=boxes, backend="device",
                                verify_batch=8)
     try:
         g0 = device.backend.stats.gathered_bytes
+        i0 = device.backend.stats.inplace_rows
         got = _answers(device)
         delta = device.backend.stats.gathered_bytes - g0
-        assert "masksearch_backend_gathered_bytes" in device.metrics_text()
+        inplace = device.backend.stats.inplace_rows - i0
+        text = device.metrics_text()
+        assert "masksearch_backend_gathered_bytes" in text
+        assert "masksearch_backend_inplace_rows" in text
         assert all(b["stats"]["resident_bytes"] == 0 for b in _answers(host))
     finally:
         host.close()
@@ -106,3 +112,27 @@ def test_resident_bytes_sum_to_the_gathered_bytes():
     assert delta % store.row_nbytes == 0
     assert all(b > 0 for b, a in zip(per_query, got)
                if a["stats"]["n_verified"])
+    pair = [b for b, sql in zip(per_query, QUERIES) if "IOU(" in sql]
+    cp_and_grouped = sum(per_query) - sum(pair)
+    assert inplace * store.row_nbytes == cp_and_grouped > 0
+    assert sum(pair) > 0
+
+
+def test_packed_steps_read_no_rows_in_place():
+    """The packed tier's steps gather their batches: its window counts
+    gathered bytes and no in-place rows."""
+    store, boxes = _store(32)
+    masks = (store.resident_masks() > 0.5).astype(np.float32)
+    packed = MaskStore.create_memory(masks, store.meta.copy(), store.cfg,
+                                     packed=True)
+    device = MaskSearchService(packed, provided_rois=boxes,
+                               backend="device", verify_batch=8)
+    try:
+        got = _answers(device)
+        stats = device.backend.stats
+        assert "masksearch_backend_inplace_rows 0" in device.metrics_text()
+    finally:
+        device.close()
+    assert sum(b["stats"]["resident_bytes"] for b in got) \
+        == stats.gathered_bytes > 0
+    assert stats.inplace_rows == 0

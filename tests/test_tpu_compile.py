@@ -2,8 +2,9 @@
 attached) v5e chip, at the deployment's shapes: B = 256 float32 224×224
 masks, packed rows of 7 words, Q = 4 descriptors, S = 2 mask types, CHI
 grid 16 × 16 bins.  Also one sharded kernel step on ``v5e:2x2``, and the
-device backend's gathering steps over resident stores of 2-D mask rows
-and of float masks in lanes of 128.
+device backend's steps over resident stores of 2-D mask rows and of float
+masks in lanes of 128, whose CP and grouped kernels read the rows in
+place.
 
 Nothing runs: a compile that passes here is not a chip run.  It catches
 what the chip's compiler refuses (block shapes off the (8, 128) tiling,
@@ -198,13 +199,16 @@ LANE_STEPS = {"_device_multi_counts": B, "_device_group_counts": 2 * B,
 
 
 @pytest.mark.parametrize("name", sorted(LANE_STEPS))
-def test_float_step_gathers_a_batch_from_lane_rows(name, one_chip,
-                                                   tpu_dispatch):
-    """Over float rows in lanes of 128 each step's temporaries are the
-    size of its batch (256 rows, 512 for the grouped step; 0.2–0.4 GB a
-    copy), under 1 GB.  Over 2-D rows ``(n, 200704)`` XLA passes the whole
-    store through column slabs: as many temporaries as the store holds
-    (7.2 GB at 9,000 masks), and no fit at 14,000."""
+def test_float_step_reads_lane_rows_in_place(name, one_chip, tpu_dispatch):
+    """Over float rows in lanes of 128 the CP and grouped steps hand the
+    whole store and the positions to their kernels, which read each row
+    where it lies: under 50 MB of temporaries, against 0.2–0.9 GB for a
+    gathered, copied and relaid-out batch, and no batch-sized
+    ``f32[B,1568,128]`` or ``f32[B,448,448]`` value in the program.  The
+    pair pass's ``gather`` still gathers a batch, 0.2 GB, under 1 GB.
+    Over 2-D rows ``(n, 200704)`` XLA passes the whole store through
+    column slabs: as many temporaries as the store holds (7.2 GB at 9,000
+    masks), and no fit at 14,000."""
     from repro.core import backend as be
 
     b = LANE_STEPS[name]
@@ -221,7 +225,39 @@ def test_float_step_gathers_a_batch_from_lane_rows(name, one_chip,
     static = {"s": S} if "group" in name else {}
     compiled = getattr(be, name).lower(*args, row_shape=(448, 448),
                                        **static).compile()
-    assert compiled.memory_analysis().temp_size_in_bytes < 1e9
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    if name == "gather":
+        assert temp < 1e9
+        return
+    batch = re.compile(rf"f32\[{b},(1568,128|448,448)\]")
+    assert not [ln for ln in compiled.as_text().splitlines()
+                if batch.search(ln)]
+    assert "tpu_custom_call" in compiled.as_text()
+    assert temp < 50e6
+
+
+@pytest.mark.parametrize("name,s", [("_device_multi_counts", 1),
+                                    ("_device_group_counts", 4)])
+def test_inplace_step_tiles_rows_past_the_vmem_budget(name, s, one_chip,
+                                                      tpu_dispatch):
+    """A 2048 × 2048 float row is 16 MB: read whole, its double-buffered
+    blocks (four members' for the grouped step) overflow VMEM.  The
+    in-place kernels read such rows in tiles of lane rows, so the steps
+    compile at any mask size."""
+    from repro.core import backend as be
+
+    def spec(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    rows = spec((64, 2048 * 2048 // 128, 128), jnp.float32)
+    vals = spec((1,), jnp.float32)
+    if s == 1:
+        args, static = (rows, spec((16,)), spec((1, 16, 4)), vals, vals), {}
+    else:
+        args = (rows, spec((8 * s,)), spec((8, 4)), spec((), jnp.float32))
+        static = {"s": s}
+    compiled = getattr(be, name).lower(*args, row_shape=(2048, 2048),
+                                       **static).compile()
+    assert "tpu_custom_call" in compiled.as_text()
 
 
 def test_device_bounds_step_gathers_chi_rows_without_relayout(one_chip):
