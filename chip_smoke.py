@@ -41,7 +41,7 @@ import sys
 import time
 
 ROOT = os.path.dirname(os.path.abspath(__file__))
-PAPER_MASKS = 22275     # the MaskSearch paper's workload (benchmarks/run.py --full)
+PAPER_MASKS = 22275     # the MaskSearch paper's iWildCam workload
 SIZE = 224
 INGEST_MASKS = 256
 INGEST_REQUEST = 64     # masks per /v1/ingest body: stays under the tier's 64 MiB cap

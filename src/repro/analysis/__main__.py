@@ -11,7 +11,7 @@ import sys
 from .core import (SUPPRESSION_FILE, all_rules, report_json, report_text,
                    run_paths)
 
-_DEFAULT_PATHS = ("src", "benchmarks", "examples")
+_DEFAULT_PATHS = ("src", "examples")
 
 
 def main(argv: list[str] | None = None) -> int:

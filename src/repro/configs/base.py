@@ -24,14 +24,6 @@ ARCH_IDS = (
     "whisper_large_v3",
 )
 
-# Input-shape suite shared by every LM arch (assignment table).
-SHAPES = {
-    "train_4k": dict(kind="train", seq_len=4096, global_batch=256),
-    "prefill_32k": dict(kind="prefill", seq_len=32768, global_batch=32),
-    "decode_32k": dict(kind="decode", seq_len=32768, global_batch=128),
-    "long_500k": dict(kind="decode", seq_len=524288, global_batch=1),
-}
-
 
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
@@ -104,10 +96,6 @@ class ModelConfig:
     # memory-efficient attention: query-block size (0 = unblocked).  Blocks
     # are unrolled (not scanned) so cost_analysis counts their FLOPs.
     attn_q_block: int = 1024
-    # Unroll the layer-group scan (cost-measurement variants only: XLA's
-    # cost_analysis counts a while body once, so the roofline 1g/2g compiles
-    # must not scan).  Production configs keep the scan for compile time.
-    unroll_groups: bool = False
     # Pure-DP mapping for small models (§Perf iter 8): batch shards over the
     # WHOLE mesh (incl. "model"), params replicate over "model" (vocab dim
     # excepted) — per-layer TP all-reduces vanish; the only large collective

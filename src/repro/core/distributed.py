@@ -3,30 +3,22 @@
 The paper's prototype is single-node; this module is the beyond-paper
 scale-out.  The mask DB (mask bytes + CHI tables + ROI table) is sharded
 row-wise over every mesh axis (a DB of N masks becomes N/num_devices rows per
-chip).  Four device-side *step* functions cover the engine's hot paths; each
-is jit-compiled with explicit shardings (steps that launch a Pallas kernel
-run it per shard under ``jax.shard_map``) and is what the multi-pod dry-run
-lowers for the "masksearch" cells:
+chip).  Each *step* function below is one physical primitive of
+:class:`repro.core.backend.MeshBackend`, which drives them from the public
+query path (``run_plan(plan, backend="mesh")``); each is jit-compiled with
+explicit shardings, and steps that launch a Pallas kernel run it per shard
+under ``jax.shard_map``:
 
-  * ``filter_bounds_step`` — CHI bounds + predicate verdicts for every local
-    row.  Collective-free (embarrassingly parallel); one ``psum`` reports
-    global accept/undecided counts.
-  * ``verify_step``        — exact CP over a dense batch of survivor masks
-    (the verification round; Pallas kernel on TPU).
-  * ``topk_step``          — bound-driven distributed top-k: per-shard
-    ``lax.top_k`` over upper bounds, ``all_gather`` of k candidates per
-    shard, global threshold τ = k-th best lower bound, survivor flags.
-  * ``iou_agg_step``       — fused thresholded intersection/union counts for
-    group (MASK_AGG) queries.
-
-Since the backend refactor these step functions are no longer a parallel
-universe: :class:`repro.core.backend.MeshBackend` drives them from the
-public query path (``run_plan(plan, backend="mesh")``) — the bounds step is
-the CP leaf of every mesh bounds pass, ``verify_step`` answers verification
-batches, ``topk_select_step`` is the ranking frontier's collective, and
-``mask_agg_step`` serves MASK_AGG group verification.  The original
-fused-verdict steps (``filter_bounds_step``/``topk_step``/``iou_agg_step``)
-remain for the dry-run's lowered cells and the multi-device tests.
+  * ``chi_bounds_step``   — the CP leaf of every mesh bounds pass (CHI
+    tables in, ``(lb, ub)`` out; collective-free).
+  * ``verify_step`` / ``cp_multi_step`` — exact CP over a dense batch of
+    survivor masks, one descriptor or several in one pass.
+  * ``topk_select_step``  — the ranking frontier's collective: the global
+    k-th best pessimistic bound from one ``all_gather`` per shard.
+  * ``mask_agg_step``, ``pair_counts_step``, ``pair_cells_step`` — MASK_AGG
+    group verification, pair verification and pair bounds.
+  * ``*_packed_step`` and ``fused_verify_step`` — the same over the packed
+    binary tier's words, and its bounds+verify megakernel.
 
 Device placement convention: rows are sharded over the flattened mesh
 (``("pod","data","model")`` or ``("data","model")``); nothing is replicated
@@ -42,16 +34,12 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..kernels import ops as kops
 from . import chi as chi_lib
-from . import cp as cp_lib
 from .exprs import cell_counts_jnp, pair_cell_bounds_jnp
+
 
 def db_axes(mesh: Mesh) -> tuple[str, ...]:
     """All mesh axes — DB rows shard over the full device set."""
     return tuple(mesh.axis_names)
-
-
-def row_sharding(mesh: Mesh, ndim: int) -> NamedSharding:
-    return NamedSharding(mesh, P(db_axes(mesh), *([None] * (ndim - 1))))
 
 
 def replicated(mesh: Mesh) -> NamedSharding:
@@ -111,43 +99,6 @@ def device_resolve(rois, row_bounds, col_bounds):
     return corners, area
 
 
-def make_filter_bounds_step(mesh: Mesh, op: str = "<"):
-    """Build the jitted distributed bounds+verdict pass.
-
-    Signature: (chi_tables (N,G+1,G+1,NB+1), rois (N,4), row_bounds, col_bounds,
-                value_ks (4,) int32 [kl_in,ku_in,kl_out,ku_out], threshold ())
-      → accept (N,) bool, undecided (N,) bool, counts (2,) int32 global.
-    """
-    axes = db_axes(mesh)
-
-    def step(tables, rois, row_bounds, col_bounds, value_ks, threshold):
-        corners, area = device_resolve(rois, row_bounds, col_bounds)
-        kl_in, ku_in, kl_out, ku_out = (value_ks[0], value_ks[1],
-                                        value_ks[2], value_ks[3])
-        lb, ub = _bounds_from_corners(tables, corners, area,
-                                      kl_in, ku_in, kl_out, ku_out)
-        if op in ("<", "<="):
-            accept = (ub < threshold) if op == "<" else (ub <= threshold)
-            reject = (lb >= threshold) if op == "<" else (lb > threshold)
-        else:
-            accept = (lb > threshold) if op == ">" else (lb >= threshold)
-            reject = (ub <= threshold) if op == ">" else (ub < threshold)
-        undecided = ~(accept | reject)
-        counts = jnp.stack([jnp.sum(accept.astype(jnp.int32)),
-                            jnp.sum(undecided.astype(jnp.int32))])
-        return accept, undecided, counts
-
-    row = NamedSharding(mesh, P(axes))
-    row2 = NamedSharding(mesh, P(axes, None))
-    rep = replicated(mesh)
-    return jax.jit(
-        step,
-        in_shardings=(NamedSharding(mesh, P(axes, None, None, None)),
-                      row2, rep, rep, rep, rep),
-        out_shardings=(row, row, rep),
-    )
-
-
 def make_verify_step(mesh: Mesh):
     """Exact CP over a dense survivor batch, rows sharded over all devices.
 
@@ -159,47 +110,6 @@ def make_verify_step(mesh: Mesh):
     return _per_shard(mesh, kops.cp_count,
                       (P(axes, None, None), P(axes, None), P(), P()),
                       P(axes))
-
-
-def make_topk_step(mesh: Mesh, k: int, desc: bool = True):
-    """Bound-driven distributed top-k candidate selection (one shard_map).
-
-    Per device: bounds → local top-k upper bounds (optimistic candidates) and
-    local top-k lower bounds (pessimistic threshold contributors).  One
-    ``all_gather`` each merges them; τ = k-th best gathered lower bound; every
-    local row with ub ≥ τ survives to verification.
-
-    Signature: (chi_tables, rois, row_bounds, col_bounds, value_ks)
-      → (cand_vals (D*k,), cand_ids (D*k,), tau (), survivors (N,) bool)
-    """
-    axes = db_axes(mesh)
-    n_dev = int(np.prod([mesh.shape[a] for a in axes]))
-
-    def local(tables, rois, row_bounds, col_bounds, value_ks, base_ids):
-        corners, area = device_resolve(rois, row_bounds, col_bounds)
-        lb, ub = _bounds_from_corners(
-            tables, corners, area,
-            value_ks[0], value_ks[1], value_ks[2], value_ks[3])
-        score_opt = ub if desc else -lb
-        score_pes = lb if desc else -ub
-        top_opt, idx_opt = jax.lax.top_k(score_opt, k)
-        top_pes, _ = jax.lax.top_k(score_pes, k)
-        gathered_opt = jax.lax.all_gather(top_opt, axes, tiled=True)
-        gathered_ids = jax.lax.all_gather(base_ids[idx_opt], axes, tiled=True)
-        gathered_pes = jax.lax.all_gather(top_pes, axes, tiled=True)
-        # τ: k-th best pessimistic score globally
-        tau = jax.lax.top_k(gathered_pes, k)[0][-1]
-        survivors = score_opt >= tau
-        return gathered_opt, gathered_ids, tau, survivors
-
-    mapped = jax.shard_map(
-        local, mesh=mesh,
-        in_specs=(P(axes, None, None, None), P(axes, None), P(), P(), P(),
-                  P(axes)),
-        out_specs=(P(), P(), P(), P(axes)),
-        check_vma=False,
-    )
-    return jax.jit(mapped), n_dev * k
 
 
 def value_ks(cfg: chi_lib.CHIConfig, lv: float, uv: float) -> np.ndarray:
@@ -220,9 +130,7 @@ def make_chi_bounds_step(mesh: Mesh):
     """The CP-leaf bounds pass, sharded: CHI tables in, (lb, ub) out.
 
     Collective-free (each row's 8-corner gather is local); this is what the
-    mesh backend runs once per distinct CP term of a plan — the generic
-    analogue of ``filter_bounds_step``, which additionally folds in one
-    comparison verdict.
+    mesh backend runs once per distinct CP term of a plan.
 
     Signature: (chi_tables (N,G+1,G+1,NB+1), rois (N,4), row_bounds,
                 col_bounds, value_ks (4,) int32) → lb (N,), ub (N,) int32.
@@ -247,11 +155,11 @@ def make_chi_bounds_step(mesh: Mesh):
 def make_topk_select_step(mesh: Mesh, k: int):
     """Distributed selection of the global k-th best pessimistic score.
 
-    The collective at the heart of ``topk_step``, but over *precomputed*
-    bounds scores instead of re-deriving them from CHI tables — so any
-    ranking expression the plan IR can express (ratios, sums of CPs)
-    shards.  Per device: mask non-definite rows to −inf, local top-k, one
-    ``all_gather`` of (value, row-id) pairs, global top-k.  Returns the
+    The collective runs over *precomputed* bounds scores rather than
+    re-deriving them from CHI tables — so any ranking expression the plan
+    IR can express (ratios, sums of CPs) shards.  Per device: mask
+    non-definite rows to −inf, local top-k, one ``all_gather`` of
+    (value, row-id) pairs, global top-k.  Returns the
     *row id* of the k-th best so the caller can read the threshold τ back
     at full host precision rather than float32.
 
@@ -277,9 +185,8 @@ def make_topk_select_step(mesh: Mesh, k: int):
 
 def make_mask_agg_step(mesh: Mesh):
     """Fused thresholded intersection/union *counts* for MASK_AGG group
-    verification, group rows sharded over all devices (the counts-level
-    sibling of ``iou_agg_step``; on TPU dispatches to the Pallas
-    ``mask_agg`` kernel).
+    verification, group rows sharded over all devices (on TPU dispatches
+    to the Pallas ``mask_agg`` kernel).
 
     Signature: (group_masks (G,S,H,W), rois (G,4), thresh ())
       → (inter (G,), union (G,)) int32.
@@ -431,77 +338,3 @@ def make_fused_verify_step(mesh: Mesh):
                       (P(axes, None, None), P(None, axes, None), P(), P(),
                        qb, qb),
                       qb)
-
-
-def make_iou_agg_step(mesh: Mesh):
-    """Fused group IoU: masks (Ngroups, n_types, H, W) → IoU scores.
-
-    Signature: (group_masks, rois (Ngroups,4), thresh ()) → iou (Ngroups,) f32.
-    On TPU dispatches to the Pallas ``mask_agg_iou`` kernel.
-    """
-    axes = db_axes(mesh)
-
-    def step(group_masks, rois, thresh):
-        binary = group_masks > thresh
-        inter = jnp.all(binary, axis=1)
-        union = jnp.any(binary, axis=1)
-        h, w = group_masks.shape[-2:]
-        inside = cp_lib._roi_mask(rois, h, w)
-        inter_ct = jnp.sum(inter & inside, axis=(1, 2)).astype(jnp.float32)
-        union_ct = jnp.sum(union & inside, axis=(1, 2)).astype(jnp.float32)
-        return jnp.where(union_ct > 0, inter_ct / jnp.maximum(union_ct, 1), 0.0)
-
-    return jax.jit(
-        step,
-        in_shardings=(NamedSharding(mesh, P(axes, None, None, None)),
-                      NamedSharding(mesh, P(axes, None)),
-                      replicated(mesh)),
-        out_shardings=NamedSharding(mesh, P(axes)),
-    )
-
-
-# ---------------------------------------------------------------------------
-# Host-side distributed query driver (runs the steps; used on real meshes and
-# in the multi-device CPU tests)
-# ---------------------------------------------------------------------------
-
-
-class DistributedEngine:
-    """Thin host orchestrator over the step functions for a sharded DB."""
-
-    def __init__(self, mesh: Mesh, cfg: chi_lib.CHIConfig):
-        self.mesh = mesh
-        self.cfg = cfg
-        self._filter_steps: dict[str, object] = {}
-        self._verify = make_verify_step(mesh)
-        self._topk_steps: dict[tuple, object] = {}
-
-    def _value_ks(self, lv: float, uv: float) -> np.ndarray:
-        return value_ks(self.cfg, lv, uv)
-
-    def filter_bounds(self, tables, rois, lv, uv, op, threshold):
-        if op not in self._filter_steps:
-            self._filter_steps[op] = make_filter_bounds_step(self.mesh, op)
-        rb = jnp.asarray(self.cfg.row_bounds, jnp.int32)
-        cb = jnp.asarray(self.cfg.col_bounds, jnp.int32)
-        return self._filter_steps[op](
-            tables, jnp.asarray(rois, jnp.int32), rb, cb,
-            jnp.asarray(self._value_ks(lv, uv)),
-            jnp.asarray(threshold, jnp.int32))
-
-    def verify(self, masks, rois, lv, uv):
-        return self._verify(masks, jnp.asarray(rois, jnp.int32),
-                            jnp.float32(lv), jnp.float32(uv))
-
-    def topk_candidates(self, tables, rois, lv, uv, k, desc=True, ids=None):
-        key = (k, desc)
-        if key not in self._topk_steps:
-            self._topk_steps[key] = make_topk_step(self.mesh, k, desc)[0]
-        n = tables.shape[0]
-        if ids is None:
-            ids = jnp.arange(n, dtype=jnp.int32)
-        rb = jnp.asarray(self.cfg.row_bounds, jnp.int32)
-        cb = jnp.asarray(self.cfg.col_bounds, jnp.int32)
-        return self._topk_steps[key](
-            tables, jnp.asarray(rois, jnp.int32), rb, cb,
-            jnp.asarray(self._value_ks(lv, uv)), ids)

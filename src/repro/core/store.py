@@ -16,8 +16,7 @@ Metadata + the CHI table are small and always memory/HBM-resident; mask
                cache.
 * ``memory`` — a host ndarray (the "hot" tier; also what a TPU host RAM tier
                looks like).
-* ``device`` — a jnp array (HBM-resident, used by the distributed shard_map
-               engine and the dry-run).
+* ``device`` — a jnp array (HBM-resident).
 
 The engine only sees :meth:`load` / :meth:`load_all`, so tiers are
 interchangeable.

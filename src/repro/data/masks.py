@@ -1,4 +1,4 @@
-"""Synthetic saliency-mask generator for benchmarks/examples.
+"""Synthetic saliency-mask generator for tests, examples and the benchmark.
 
 Real model-saliency maps (the paper's iWildCam Grad-CAM masks) are smooth,
 blobby, spatially coherent fields — which is exactly why CHI prunes well on

@@ -4,7 +4,7 @@ Single pod: 16×16 = 256 chips, axes ("data", "model").
 Multi-pod:  2×16×16 = 512 chips, axes ("pod", "data", "model").
 
 Defined as functions so importing this module never touches jax device
-state — the dry-run must set XLA_FLAGS before any jax initialization.
+state: a caller may still set XLA_FLAGS before jax initializes.
 """
 
 from __future__ import annotations
@@ -29,9 +29,3 @@ def make_local_mesh(shape=None, axes=None):
     if shape is None:
         shape, axes = (n,), ("data",)
     return _auto_mesh(shape, axes)
-
-
-# TPU v5e constants used by the roofline analysis (assignment-provided).
-PEAK_FLOPS_BF16 = 197e12        # per chip
-HBM_BW = 819e9                  # bytes/s per chip
-ICI_BW = 50e9                   # bytes/s per link

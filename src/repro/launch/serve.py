@@ -3,8 +3,8 @@
     PYTHONPATH=src python -m repro.launch.serve --arch granite_3_2b --smoke \
         --batch 4 --prompt-len 16 --gen 32
 
-Demonstrates the serve_step path the decode_* dry-run cells lower: the cache
-layout, position bookkeeping, and (on a real mesh) seq-sharded KV.
+Demonstrates the serve_step path: the cache layout, position bookkeeping,
+and (on a real mesh) seq-sharded KV.
 """
 
 from __future__ import annotations

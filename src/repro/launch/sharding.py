@@ -135,10 +135,6 @@ def install_activation_rules(mesh: Mesh, cfg=None) -> None:
     L.set_activation_rule(rule)
 
 
-def clear_activation_rules() -> None:
-    L.set_activation_rule(None)
-
-
 # -- cache shardings (decode / prefill) --------------------------------------
 
 _CACHE_LEAF_AXES = {
@@ -181,16 +177,6 @@ def cache_sharding_tree(mesh: Mesh, cache_shapes: Any):
                                             leaf.shape))
 
     return jax.tree_util.tree_map_with_path(assign, cache_shapes)
-
-
-def batch_sharding_tree(mesh: Mesh, batch_shapes: Any, cfg=None):
-    """Token/label/feature batches: shard axis 0 (batch) over data axes."""
-    rules = rules_for(cfg, mesh)[1]
-
-    def assign(leaf):
-        axes = ("batch",) + (None,) * (len(leaf.shape) - 1)
-        return NamedSharding(mesh, spec_for(mesh, rules, axes, leaf.shape))
-    return jax.tree.map(assign, batch_shapes)
 
 
 def replicated(mesh: Mesh):

@@ -1,14 +1,13 @@
 """GQA attention: full/local variants, qk-norm, RoPE, KV cache, SP decode.
 
-Two memory/perf-critical design points (hit during the dry-run iteration —
-see EXPERIMENTS.md §Perf):
+Two memory/perf-critical design points:
 
 * **Chunked (memory-efficient) attention.**  Materializing (S × S) f32
   scores at 4k–32k sequence lengths costs tens of GB per device; queries are
   processed in unrolled blocks of ``cfg.attn_q_block`` (exact row softmax per
   block — no online accumulation needed since each block sees all its keys).
   Blocks are a static python loop, NOT a scan, so ``cost_analysis`` counts
-  their FLOPs (the roofline methodology depends on this).
+  their FLOPs.
 
 * **Local layers slice K/V.**  Sliding-window layers (gemma3 5:1,
   recurrentgemma) gather only the ``q_block + window`` keys a block can see —

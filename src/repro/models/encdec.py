@@ -20,7 +20,6 @@ import jax.numpy as jnp
 
 from . import attention as attn
 from .layers import (cross_entropy, embed, gelu_mlp, init_embedding,
-                     maybe_scan,
                      init_gelu_mlp, init_rms, logits_from_tied,
                      rms_norm, shard_act, sinusoidal_positions, split_params)
 
@@ -99,7 +98,7 @@ class EncDecLM:
         if cfg.remat:
             body = jax.checkpoint(
                 body, policy=jax.checkpoint_policies.nothing_saveable)
-        x, _ = maybe_scan(body, x, params["enc"], cfg.unroll_groups)
+        x, _ = jax.lax.scan(body, x, params["enc"])
         return rms_norm(x, params["enc_norm"], cfg.norm_eps)
 
     # -- decoder (train) -------------------------------------------------------
@@ -125,7 +124,7 @@ class EncDecLM:
         if cfg.remat:
             body = jax.checkpoint(
                 body, policy=jax.checkpoint_policies.nothing_saveable)
-        x, _ = maybe_scan(body, x, params["dec"], cfg.unroll_groups)
+        x, _ = jax.lax.scan(body, x, params["dec"])
         return rms_norm(x, params["dec_norm"], cfg.norm_eps)
 
     def loss(self, params, batch):
@@ -182,8 +181,7 @@ class EncDecLM:
             newc = {"k": sc["k"], "v": sc["v"], "xk": kv["k"], "xv": kv["v"]}
             return x, newc
 
-        x, cache["dec"] = maybe_scan(body, x, (params["dec"], cache["dec"]),
-                                     cfg.unroll_groups)
+        x, cache["dec"] = jax.lax.scan(body, x, (params["dec"], cache["dec"]))
         h = rms_norm(x[:, -1:], params["dec_norm"], cfg.norm_eps)
         return logits_from_tied(params["embedding"], h, cfg.vocab_size), cache
 
@@ -209,8 +207,7 @@ class EncDecLM:
             newc = {"k": sc["k"], "v": sc["v"], "xk": c["xk"], "xv": c["xv"]}
             return x, newc
 
-        x, cache["dec"] = maybe_scan(body, x, (params["dec"], cache["dec"]),
-                                     cfg.unroll_groups)
+        x, cache["dec"] = jax.lax.scan(body, x, (params["dec"], cache["dec"]))
         h = rms_norm(x, params["dec_norm"], cfg.norm_eps)
         return logits_from_tied(params["embedding"], h, cfg.vocab_size), cache
 

@@ -94,28 +94,6 @@ def shard_act(x: Array, axes: tuple[str | None, ...]) -> Array:
 # ---------------------------------------------------------------------------
 
 
-def maybe_scan(body, carry, xs, unroll: bool):
-    """lax.scan, or a python-unrolled equivalent when ``unroll``.
-
-    The unrolled form exists for the roofline cost compiles: XLA's
-    cost_analysis counts a while-loop body once regardless of trip count, so
-    the 1-group/2-group measurement variants must not scan.
-    """
-    if not unroll:
-        return jax.lax.scan(body, carry, xs)
-    n = jax.tree.leaves(xs)[0].shape[0]
-    ys = []
-    for i in range(n):
-        x_i = jax.tree.map(lambda a, i=i: a[i], xs)
-        carry, y = body(carry, x_i)
-        ys.append(y)
-    if ys and ys[0] is not None:
-        ys = jax.tree.map(lambda *a: jnp.stack(a), *ys)
-    else:
-        ys = None
-    return carry, ys
-
-
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
 def rms_norm(x: Array, weight: Array, eps: float = 1e-6) -> Array:
     """RMSNorm with a hand-written VJP (the fused-layernorm-backward

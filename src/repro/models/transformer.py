@@ -9,8 +9,7 @@ Layer stacking follows the config's ``layer_pattern`` repeating unit
 
 Group parameters are stacked pytrees (leading "layers" axis) built by
 vmapping the group initializer.  Scan keeps compile time flat across 24–64
-layer models; the roofline extractor linearizes costs from 1-group/2-group
-unrolled compiles (launch/dryrun.py).
+layer models.
 
 Block kinds: "global"/"local" (GQA or MLA attention + FFN), "rglru"
 (recurrent block + FFN), "ssm" (Mamba-2 block, no separate FFN).
@@ -31,7 +30,6 @@ from . import moe as moe_lib
 from . import rglru as rglru_lib
 from . import ssm as ssm_lib
 from .layers import (Param, cross_entropy, embed, init_embedding, init_rms,
-                     maybe_scan,
                      init_swiglu, logits_from_tied, param, rms_norm, shard_act,
                      split_params, swiglu)
 
@@ -270,8 +268,7 @@ class DecoderLM:
                 x, a_g = group_fn(x, gp)
                 return (x, aux + a_g), None
 
-            (x, aux), _ = maybe_scan(body, (x, aux), params["groups"],
-                                     cfg.unroll_groups)
+            (x, aux), _ = jax.lax.scan(body, (x, aux), params["groups"])
         for i, kind in enumerate(self.tail_kinds):
             x, a = apply_block(params["tail"][f"block{i}"], cfg, kind, moe, x,
                                positions)
@@ -360,9 +357,8 @@ class DecoderLM:
                         gp[f"block{i}"], cfg, kind, moe, x, positions,
                         gc[f"block{i}"])
                 return x, newc
-            x, cache["groups"] = maybe_scan(
-                body, x, (params["groups"], cache["groups"]),
-                cfg.unroll_groups)
+            x, cache["groups"] = jax.lax.scan(
+                body, x, (params["groups"], cache["groups"]))
         for i, kind in enumerate(self.tail_kinds):
             x, cache["tail"][f"block{i}"] = apply_block_prefill(
                 params["tail"][f"block{i}"], cfg, kind, moe, x, positions,
@@ -389,9 +385,8 @@ class DecoderLM:
                         gp[f"block{i}"], cfg, kind, moe, x, pos,
                         gc[f"block{i}"])
                 return x, newc
-            x, cache["groups"] = maybe_scan(
-                body, x, (params["groups"], cache["groups"]),
-                cfg.unroll_groups)
+            x, cache["groups"] = jax.lax.scan(
+                body, x, (params["groups"], cache["groups"]))
         for i, kind in enumerate(self.tail_kinds):
             x, cache["tail"][f"block{i}"] = apply_block_decode(
                 params["tail"][f"block{i}"], cfg, kind, moe, x, pos,

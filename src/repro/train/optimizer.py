@@ -31,7 +31,7 @@ class OptConfig:
     # Memory policy.  Default: fp32 moments + fp32 master weights
     # (14 B/param with bf16 params).  Low-mem mode for the ≥200B MoE cells:
     # bf16 moments, no master (6 B/param) — production would use 8-bit
-    # moments instead; the roofline table records which mode each cell used.
+    # moments instead.
     moments_dtype: str = "float32"
     use_master: bool = True
 

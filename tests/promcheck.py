@@ -1,4 +1,4 @@
-"""Stdlib-only Prometheus text-exposition checker for the CI smoke leg.
+"""Stdlib-only Prometheus text-exposition checker.
 
 Validates the output of ``GET /metrics`` without depending on a Prometheus
 client library:
@@ -12,10 +12,10 @@ client library:
   * counters are non-negative.
 
 ``--require NAME`` (repeatable) additionally asserts that a family is
-present — the CI leg uses it to pin the families the observability layer
-promises.
+present, pinning the families the observability layer promises.  The tests
+call :func:`parse`, which checks a text and returns its sample values.
 
-    curl -s localhost:8080/metrics | python benchmarks/check_prometheus.py \
+    curl -s localhost:8080/metrics | python tests/promcheck.py \
         --require masksearch_queries_total
 """
 
@@ -43,8 +43,10 @@ def _family_of(name: str, typed: dict) -> str | None:
     return None
 
 
-def check(text: str, required: list[str]) -> list[str]:
+def check(text: str, required=()) -> tuple[list[str], dict, dict]:
+    """``(errors, {"name{labels}": value}, {family: type})`` of ``text``."""
     errors: list[str] = []
+    samples: dict[str, float] = {}
     typed: dict[str, str] = {}
     helped: set[str] = set()
     # histogram buckets keyed by (family, non-le labels) -> [(le, count)]
@@ -82,6 +84,7 @@ def check(text: str, required: list[str]) -> list[str]:
         except ValueError:
             errors.append(f"line {lineno}: non-numeric value {raw_value!r}")
             continue
+        samples[line.rsplit(" ", 1)[0]] = value
         labels = {}
         for pair in (raw_labels.split(",") if raw_labels else []):
             if not _LABEL_RE.match(pair):
@@ -144,7 +147,14 @@ def check(text: str, required: list[str]) -> list[str]:
     for want in required:
         if want not in typed:
             errors.append(f"required family {want!r} absent from exposition")
-    return errors
+    return errors, samples, typed
+
+
+def parse(text: str, required=()) -> tuple[dict, dict]:
+    """:func:`check` that raises AssertionError listing every fault."""
+    errors, samples, typed = check(text, required)
+    assert not errors, "\n".join(errors)
+    return samples, typed
 
 
 def main() -> int:
@@ -161,17 +171,14 @@ def main() -> int:
         with open(args.path) as f:
             text = f.read()
 
-    errors = check(text, args.require)
-    families = text.count("# TYPE ")
-    samples = sum(1 for ln in text.splitlines()
-                  if ln.strip() and not ln.startswith("#"))
+    errors, samples, typed = check(text, args.require)
+    counts = f"{len(typed)} families, {len(samples)} samples"
     if errors:
-        print(f"prometheus check FAILED ({families} families, "
-              f"{samples} samples):", file=sys.stderr)
+        print(f"prometheus check FAILED ({counts}):", file=sys.stderr)
         for e in errors:
             print(f"  {e}", file=sys.stderr)
         return 1
-    print(f"prometheus check ok: {families} families, {samples} samples")
+    print(f"prometheus check ok: {counts}")
     return 0
 
 

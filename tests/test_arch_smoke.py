@@ -1,8 +1,8 @@
 """Per-architecture smoke tests (reduced configs, CPU).
 
 For every assigned arch: one forward/loss, one SGD-free grad step, one
-prefill + two decode steps.  Asserts output shapes and finiteness — the
-full configs are exercised only through the dry-run (no allocation).
+prefill + two decode steps.  Asserts output shapes and finiteness; the
+full configs are not exercised.
 """
 
 import jax

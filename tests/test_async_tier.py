@@ -9,6 +9,7 @@ import urllib.request
 
 import pytest
 
+import promcheck
 from repro.service import MaskSearchService
 from repro.service.admission import (AdmissionController, FairQueue,
                                      TokenBucket)
@@ -202,6 +203,25 @@ def test_streaming_session_matches_oneshot(tier):
     assert handle.tier.stats.stream_pages >= len(pages)
     # streams drop their session on completion
     assert len(service.sessions) == 0
+
+
+def test_served_metrics_exposition_is_valid(tier):
+    """The tier's ``/v1/metrics`` text, after it has answered a query, is
+    a well-formed exposition that carries the query counter."""
+    service, handle = tier
+    code, _, _ = _raw(handle.base_url, "POST", "/v1/query",
+                      {"sql": TOPK_SQL.format(n=4)})
+    assert code == 200
+    with urllib.request.urlopen(handle.base_url + "/v1/metrics",
+                                timeout=30) as resp:
+        assert resp.status == 200
+        text = resp.read().decode()
+    samples, typed = promcheck.parse(
+        text, required=("masksearch_queries_total",
+                        "masksearch_query_phase_seconds"))
+    assert typed["masksearch_queries_total"] == "counter"
+    assert sum(v for k, v in samples.items()
+               if k.startswith("masksearch_queries_total")) >= 1
 
 
 def test_cross_tenant_fusion_in_one_batch(tier):

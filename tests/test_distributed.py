@@ -1,6 +1,6 @@
 """Multi-device tests for the distributed query engine + sharded training.
 
-The main pytest session must see 1 device (dry-run isolation), so these run
+The main pytest session sees 1 device, so these run
 in subprocesses that set XLA_FLAGS=--xla_force_host_platform_device_count=8
 before importing jax.
 """
@@ -24,9 +24,15 @@ def _run(code: str, timeout=420):
 
 
 def test_distributed_query_engine():
+    """The mesh backend's primitives on a 2x4 mesh against the exact CP
+    oracle: a CP filter's bounds verdicts, the top-k frontier, and the
+    verification counts."""
     _run("""
-import numpy as np, jax, jax.numpy as jnp
-from repro.core import chi, cp, distributed as dist
+import numpy as np, jax
+from repro.core import CP, MaskStore, chi, cp
+from repro.core.backend import MeshBackend
+from repro.core.engine import _make_context
+from repro.core.store import MASK_META_DTYPE
 from repro.data.masks import saliency_masks
 
 mesh = jax.make_mesh((2, 4), ("data", "model"),
@@ -34,28 +40,31 @@ mesh = jax.make_mesh((2, 4), ("data", "model"),
 N, H, W = 64, 64, 64
 cfg = chi.CHIConfig(grid=8, num_bins=8, height=H, width=W)
 masks = saliency_masks(N, H, W, seed=3)[0]
-tables = chi.build_chi_np(masks, cfg)
-rois = np.tile([8, 8, 56, 56], (N, 1)).astype(np.int32)
-eng = dist.DistributedEngine(mesh, cfg)
-t_sh = jax.device_put(jnp.asarray(tables), dist.row_sharding(mesh, 4))
-r_sh = jax.device_put(jnp.asarray(rois), dist.row_sharding(mesh, 2))
-lv, uv, T = 0.5, 1.0, 200
-accept, undecided, counts = eng.filter_bounds(t_sh, r_sh, lv, uv, "<", T)
-exact = np.array([cp.cp_exact_np(m, rois[0], lv, uv) for m in masks])
-acc, und = np.asarray(accept), np.asarray(undecided)
-assert np.all(exact[acc] < T)
-assert np.all(exact[~(acc | und)] >= T)
-assert int(counts[1]) < N, "bounds must decide something on blobby masks"
+meta = np.zeros(N, MASK_META_DTYPE)
+meta["mask_id"] = np.arange(N)
+meta["image_id"] = np.arange(N)
+meta["mask_type"] = 1
+store = MaskStore.create_memory(masks, meta, cfg)
+be = MeshBackend(store, mesh)
+roi, lv, uv, T = (8, 8, 56, 56), 0.5, 1.0, 200
+term = CP(roi, lv, uv)
+ctx, _, _ = _make_context(store, [term], False, None, None, None, backend=be)
+exact = np.array([cp.cp_exact_np(m, roi, lv, uv) for m in masks])
 
-vals, ids, tau, surv = eng.topk_candidates(t_sh, r_sh, lv, uv, k=5)
-top5 = set(np.argsort(-exact, kind="stable")[:5])
-assert top5.issubset(set(np.nonzero(np.asarray(surv))[0]))
-assert np.asarray(surv).sum() < N, "top-k pruning must drop candidates"
+lb, ub = be.bounds(ctx, term)
+accept, reject = ub < T, lb >= T
+assert np.all(exact[accept] < T)
+assert np.all(exact[reject] >= T)
+assert (accept | reject).any(), "bounds must decide something on blobby masks"
 
-m_sh = jax.device_put(jnp.asarray(masks), dist.row_sharding(mesh, 3))
-got = np.asarray(eng.verify(m_sh, r_sh, lv, uv))
+everyone = np.ones(N, bool)
+alive = be.topk_candidates(lb, ub, 5, True, everyone, everyone)
+assert alive[np.argsort(-exact, kind="stable")[:5]].all()
+assert alive.sum() < N, "top-k pruning must drop candidates"
+
+got = be.verify_counts(ctx, np.arange(N), [term])[term]
 assert np.array_equal(got, exact)
-print("DIST_ENGINE_OK", int(counts[1]), int(np.asarray(surv).sum()))
+print("MESH_PRIMITIVES_OK", int((accept | reject).sum()), int(alive.sum()))
 """)
 
 

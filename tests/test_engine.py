@@ -102,17 +102,28 @@ def test_mask_type_predicate(db):
 
 
 def test_multiquery_shares_loads(db):
-    from repro.core.multiquery import run_workload
+    """Related rankings through the service's batch entry point (one fused
+    drive) answer as one query at a time does, and load fewer mask bytes
+    than running them serially without sharing."""
+    from repro.service import MaskSearchService
     store, rois, _, _ = db
     sqls = ["SELECT mask_id FROM MasksDatabaseView ORDER BY "
             f"CP(mask, full_img, ({lv}, {lv + 0.3})) DESC LIMIT 10;"
             for lv in (0.2, 0.25, 0.3)]
-    store.io.reset()
-    _, ws = run_workload(store, sqls, provided_rois=rois, share_loads=True)
-    shared_files = ws.files_loaded
-    store.io.reset()
-    _, ws2 = run_workload(store, sqls, provided_rois=rois, share_loads=False)
-    assert shared_files <= ws2.files_loaded
+    svc = MaskSearchService(MaskStore.open_disk(store.root),
+                            provided_rois=rois, verify_batch=8)
+    out = svc.execute_many([{"op": "query", "sql": s} for s in sqls])
+    shared_bytes = svc.store.io.bytes_read
+    svc.close()
+
+    serial = MaskStore.open_disk(store.root)
+    want = [queries.run(s, serial, provided_rois=rois) for s in sqls]
+    # 3.75x on this data; the floor is a 2.5th of that
+    assert 0 < 1.5 * shared_bytes <= serial.io.bytes_read
+    for (status, got), ((ids, scores), _) in zip(out, want):
+        assert status == "ok", got
+        assert got["ids"] == [int(x) for x in ids]
+        np.testing.assert_allclose(got["scores"], scores)
 
 
 def test_sql_parser_errors():

@@ -19,6 +19,8 @@ import json
 import numpy as np
 import pytest
 
+import promcheck
+
 from repro.core import CHIConfig, MaskStore, queries
 from repro.core.plan import run_plan
 from repro.core.store import MASK_META_DTYPE
@@ -220,26 +222,6 @@ def test_explain_plan_render_smoke():
 # -- metrics registry --------------------------------------------------------
 
 
-def _parse_prometheus(text):
-    """Tiny exposition-format check: returns {metric: value} for plain
-    samples and validates histogram bucket monotonicity."""
-    samples = {}
-    typed = {}
-    for line in text.splitlines():
-        if not line:
-            continue
-        if line.startswith("# TYPE "):
-            _, _, name, mtype = line.split(" ")
-            typed[name] = mtype
-            continue
-        if line.startswith("#"):
-            continue
-        name_labels, value = line.rsplit(" ", 1)
-        float(value)                      # must parse
-        samples[name_labels] = float(value)
-    return samples, typed
-
-
 def test_registry_counter_gauge_histogram():
     reg = MetricsRegistry()
     c = reg.counter("t_total", "help", ("kind",))
@@ -250,7 +232,7 @@ def test_registry_counter_gauge_histogram():
     h = reg.histogram("t_seconds", "help", buckets=(0.1, 1.0))
     for v in (0.05, 0.5, 5.0):
         h.observe(v)
-    samples, typed = _parse_prometheus(reg.prometheus_text())
+    samples, typed = promcheck.parse(reg.prometheus_text())
     assert samples['t_total{kind="a"}'] == 3
     assert samples["t_gauge"] == 4.5
     assert samples['t_seconds_bucket{le="0.1"}'] == 1
@@ -282,14 +264,14 @@ def test_registry_collectors_reflect_dataclasses():
     reg = MetricsRegistry()
     reg.register_collector(dataclass_sampler("t_s", "counter", "h",
                                              lambda: S()))
-    samples, _ = _parse_prometheus(reg.prometheus_text())
+    samples, _ = promcheck.parse(reg.prometheus_text())
     assert samples == {"t_s_reads": 3.0, "t_s_frac": 0.5}
 
 
 def test_kernel_launch_metrics_populated(db):
     store, rois = db
     queries.run(CP_SQL, store, provided_rois=rois)
-    samples, _ = _parse_prometheus(REGISTRY.prometheus_text())
+    samples, _ = promcheck.parse(REGISTRY.prometheus_text())
     launches = {k: v for k, v in samples.items()
                 if k.startswith("masksearch_kernel_launches_total")}
     assert any(v > 0 for v in launches.values()), launches
