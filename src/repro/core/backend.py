@@ -203,6 +203,13 @@ class ExecBackend:
         gather none."""
         return 0
 
+    @property
+    def chi_lookup_bytes(self) -> int:
+        """Bytes of CHI entries this backend's bounds steps have looked up
+        in place (monotonic).  Backends that read whole CHI rows report
+        none, and the engine counts the rows instead."""
+        return 0
+
     def fused_counts(self, store, positions: np.ndarray,
                      specs) -> np.ndarray:
         """The scheduler's fused pass: Q ``(rois, lv, uv)`` descriptors
@@ -343,15 +350,22 @@ class HostBackend(ExecBackend):
 # ---------------------------------------------------------------------------
 
 
+# CHI entries one CP-leaf bounds step reads per candidate: 8 corners for
+# the lower bound and 8 for the upper.
+CHI_LOOKUPS = 16
+
+
 @jax.jit
 def _device_cp_bounds(tables, pos, rois, rb, cb, ks):
-    """CP-leaf bounds with the candidate gather, corner resolution and
-    8-corner lookup all on device (the filter phase leaving the host).
-    The tier is implicit in the operands — ``device_resolve`` derives the
-    grid from ``rb``'s length — so one compilation serves each tier shape."""
+    """CP-leaf bounds with corner resolution and the 8-corner lookups all
+    on device (the filter phase leaving the host).  The lookups read the
+    16 entries each candidate needs at ``(pos, i, j, k)`` of the resident
+    table as it lies; no row is gathered, copied or relaid out.  The tier
+    is implicit in the operands — ``device_resolve`` derives the grid
+    from ``rb``'s length — so one compilation serves each tier shape."""
     corners, area = device_resolve(rois, rb, cb)
-    return _bounds_from_corners(gather_rows(tables, pos), corners, area,
-                                ks[0], ks[1], ks[2], ks[3])
+    return _bounds_from_corners(tables, corners, area,
+                                ks[0], ks[1], ks[2], ks[3], pos=pos)
 
 
 @functools.partial(jax.jit, static_argnames=("stat",))
@@ -499,14 +513,18 @@ class BackendStats:
     converted host→device, bytes fetched device→host, calls into jitted
     steps or eager device ops, output arrays fetched, bytes of resident
     mask rows the verification steps read (rows × one stored row,
-    ``gathered_bytes``), and of those rows the ones a kernel read in place
-    from the resident store, with no gathered batch (``inplace_rows``)."""
+    ``gathered_bytes``), of those rows the ones a kernel read in place
+    from the resident store, with no gathered batch (``inplace_rows``),
+    and the candidates whose CP-leaf bounds were looked up in place in
+    the resident CHI table (``chi_inplace_rows``, ``CHI_LOOKUPS`` entries
+    each)."""
     h2d_bytes: int = 0
     d2h_bytes: int = 0
     device_calls: int = 0
     fetches: int = 0
     gathered_bytes: int = 0
     inplace_rows: int = 0
+    chi_inplace_rows: int = 0
 
 
 class DeviceBackend(_KthValueMixin, ExecBackend):
@@ -570,6 +588,11 @@ class DeviceBackend(_KthValueMixin, ExecBackend):
     @property
     def gathered_bytes(self) -> int:
         return self.stats.gathered_bytes
+
+    @property
+    def chi_lookup_bytes(self) -> int:
+        return (self.stats.chi_inplace_rows * CHI_LOOKUPS *
+                self._tables.dtype.itemsize)
 
     def _step(self, step: str, fn, *args, pick: int | None = None,
               fetch: bool = True, rows: int = 0, inplace: bool = False,
@@ -642,6 +665,7 @@ class DeviceBackend(_KthValueMixin, ExecBackend):
             "_device_cp_bounds", _device_cp_bounds, tables,
             np.asarray(mctx.positions), np.asarray(rois, np.int32), rb, cb,
             np.asarray(ks))
+        self.stats.chi_inplace_rows += len(mctx.positions)
         return lb.astype(np.float64), ub.astype(np.float64)
 
     def _pair_cells(self, pctx, node):
@@ -989,5 +1013,5 @@ def get_backend(store, backend=None) -> ExecBackend:
 
 
 __all__ = ["ExecBackend", "HostBackend", "DeviceBackend", "MeshBackend",
-           "BackendStats", "F32_MAX", "chi_verdicts", "get_backend", "host_backend",
-           "is_packed", "spec_arrays"]
+           "BackendStats", "CHI_LOOKUPS", "F32_MAX", "chi_verdicts",
+           "get_backend", "host_backend", "is_packed", "spec_arrays"]

@@ -323,19 +323,27 @@ def tier_alignment_fracs(cfg: CHIConfig, g: int, rois: np.ndarray):
 # ---------------------------------------------------------------------------
 
 
-def _lookup(table: Array, i0, i1, j0, j1, k0, k1) -> Array:
+def _lookup(table: Array, i0, i1, j0, j1, k0, k1, pos=None) -> Array:
     """Exact count over aligned box [i0,i1)×[j0,j1) cells × [k0,k1) bins.
 
     All index args are (B,) int32 (or scalars broadcastable to it); the
-    answer is an 8-corner inclusion–exclusion gather — O(1) per mask.  The
-    gathers index a 2-D view of the rows (see :func:`gather_rows`).
+    answer is an 8-corner inclusion–exclusion gather — O(1) per mask.
+    Without ``pos`` the table holds the B rows themselves and the gathers
+    index a 2-D view of them (see :func:`gather_rows`).  With ``pos`` (B,)
+    the table is the whole resident store and each corner is read at
+    ``table[pos, i, j, k]`` where it lies: 8 entries per mask, where a
+    row gather would first copy and relay out the whole table.
     """
-    b, _, nj, nk = table.shape
-    bi = jnp.arange(b)
-    flat = table.reshape(b, _row_size(table))
+    if pos is None:
+        b, _, nj, nk = table.shape
+        bi = jnp.arange(b)
+        flat = table.reshape(b, _row_size(table))
 
-    def f(i, j, k):
-        return flat[bi, (i * nj + j) * nk + k]
+        def f(i, j, k):
+            return flat[bi, (i * nj + j) * nk + k]
+    else:
+        def f(i, j, k):
+            return table[pos, i, j, k]
 
     def plane(k):
         return f(i1, j1, k) - f(i0, j1, k) - f(i1, j0, k) + f(i0, j0, k)

@@ -59,19 +59,23 @@ def _per_shard(mesh: Mesh, fn, in_specs, out_specs):
 # ---------------------------------------------------------------------------
 
 
-def _bounds_from_corners(table, corners, area, kl_in, ku_in, kl_out, ku_out):
+def _bounds_from_corners(table, corners, area, kl_in, ku_in, kl_out, ku_out,
+                         pos=None):
     """Same 8-corner math as chi._bounds_device, but with corner indices as
     device arrays (computed on device from boundary tables) so the whole
-    bounds pass stays on-chip."""
+    bounds pass stays on-chip.  ``table`` holds the candidates' rows, or,
+    with ``pos``, is the whole resident table read at those rows
+    (:func:`repro.core.chi._lookup`)."""
     il, ih, jl, jh, ol, oh, pl, ph = [corners[:, i] for i in range(8)]
     inner_ok = (ih > il) & (jh > jl) & (ku_in > kl_in)
     lb = jnp.where(inner_ok,
                    chi_lib._lookup(table, il, ih, jl, jh,
-                                   jnp.minimum(kl_in, ku_in), ku_in), 0)
+                                   jnp.minimum(kl_in, ku_in), ku_in, pos), 0)
     outer_ok = (oh > ol) & (ph > pl) & (ku_out > kl_out)
     ub = jnp.where(outer_ok,
                    chi_lib._lookup(table, ol, oh, pl, ph,
-                                   jnp.minimum(kl_out, ku_out), ku_out), 0)
+                                   jnp.minimum(kl_out, ku_out), ku_out, pos),
+                   0)
     ub = jnp.minimum(ub, area.astype(ub.dtype))
     lb = jnp.minimum(lb, ub)
     return lb.astype(jnp.int32), ub.astype(jnp.int32)

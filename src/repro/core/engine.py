@@ -96,6 +96,17 @@ def _chi_row_nbytes(ctx, tier: Optional[int] = None) -> int:
     return per * (2 if isinstance(ctx, PairEvalContext) else 1)
 
 
+def _chi_bytes_read(backend, looked0: int, rows: int, ctx,
+                    tier: Optional[int] = None) -> int:
+    """CHI bytes one bounds pass read: the entries the backend looked up in
+    place since ``looked0`` (the device CP leaf, ``CHI_LOOKUPS`` per
+    candidate and leaf, at any tier), else ``rows`` whole CHI rows.  Like
+    ``resident_bytes``, the backend's counter is exact for a run that has
+    the backend to itself."""
+    looked = backend.chi_lookup_bytes - looked0
+    return looked if looked else rows * _chi_row_nbytes(ctx, tier)
+
+
 def _make_context(store, exprs, group_by_image: bool, positions, mask_types,
                   provided_rois, partial_rows: bool = True, backend=None):
     """Build the evaluation context + the id array that results refer to.
@@ -267,16 +278,18 @@ class _VerifyRun:
         with _trace.span("bounds") as sp:
             cached = (self._bounds_hook.get(expr, tier=finest)
                       if self._bounds_hook else None)
+            nbytes = 0
             if cached is not None:
                 lb, ub = cached
             else:
+                looked0 = self.backend.chi_lookup_bytes
                 lb, ub = self.backend.bounds(self.ctx, expr)
                 lb = np.asarray(lb, np.float64)
                 ub = np.asarray(ub, np.float64)
                 if self._bounds_hook is not None:
                     self._bounds_hook.put(expr, lb, ub, tier=finest)
-            nbytes = (0 if cached is not None
-                      else self.n * _chi_row_nbytes(self.ctx))
+                nbytes = _chi_bytes_read(self.backend, looked0, self.n,
+                                         self.ctx)
             sp.set(expr=repr(expr), candidates=self.n,
                    cached=cached is not None, chi_bytes=nbytes)
         self.stats.chi_bytes += nbytes
@@ -440,10 +453,12 @@ def _ladder_bounds_of(run, sub, g: int, finest: int):
     def bounds_of(expr):
         t0 = time.perf_counter()
         with _trace.span("bounds.tier") as sp:
+            looked0 = run.backend.chi_lookup_bytes
             lb, ub = run.backend.bounds(sub, expr)
             lb = np.asarray(lb, np.float64)
             ub = np.asarray(ub, np.float64)
-            nbytes = len(sub.positions) * _chi_row_nbytes(sub, g)
+            nbytes = _chi_bytes_read(run.backend, looked0,
+                                     len(sub.positions), sub, g)
             sp.set(expr=repr(expr), tier=g, candidates=len(sub.positions),
                    chi_bytes=nbytes)
         run.stats.chi_bytes += nbytes

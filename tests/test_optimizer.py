@@ -8,6 +8,7 @@ import pytest
 
 from repro.core import exprs as E
 from repro.core import opt
+from repro.core.backend import get_backend
 from repro.core.chi import CHIConfig, tier_slice
 from repro.core.exprs import CP, And, Cmp, MaskEvalContext, Or, TypeIn
 from repro.core.plan import LogicalPlan, run_plan
@@ -135,17 +136,30 @@ def test_ladder_bit_identity_across_backends(backend):
     store = MaskStore.create_memory(_masks(b, seed=7), _meta(b), cfg)
     pred = And(_skewed_pred(), TypeIn((1, 2)))
     plan = LogicalPlan(predicate=pred)
+    be = get_backend(store, backend)
+    looked0 = be.chi_lookup_bytes
     with opt.configure(pyramid=False, reorder=False):
         ids_classic, st_classic = run_plan(store, plan, backend=backend)
+    looked1 = be.chi_lookup_bytes
     with opt.configure(pyramid=True, reorder=True):
         ids_ladder, st_ladder = run_plan(store, plan, backend=backend)
+        looked2 = be.chi_lookup_bytes
         ids_re, _ = run_plan(
             store, LogicalPlan(predicate=_reassoc(pred)), backend=backend)
     np.testing.assert_array_equal(ids_classic, ids_ladder)
     np.testing.assert_array_equal(sorted(ids_classic), sorted(ids_re))
     assert st_classic.n_decided_by_bounds == st_ladder.n_decided_by_bounds
     assert st_classic.n_verified == st_ladder.n_verified
-    assert st_ladder.chi_bytes <= st_classic.chi_bytes
+    if backend != "device":
+        assert st_ladder.chi_bytes <= st_classic.chi_bytes
+        return
+    # The device step looks up the same 16 entries per candidate at every
+    # tier, so a coarse rung costs no fewer bytes than the finest: each
+    # bounds pass counts 64 B per candidate, the classic path one pass per
+    # CP conjunct over every candidate.
+    assert st_classic.chi_bytes == looked1 - looked0 \
+        == 64 * 2 * st_classic.n_candidates
+    assert st_ladder.chi_bytes == looked2 - looked1 > 0
 
 
 def test_ladder_bit_identity_packed():

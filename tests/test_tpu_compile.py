@@ -260,17 +260,37 @@ def test_inplace_step_tiles_rows_past_the_vmem_budget(name, s, one_chip,
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_device_bounds_step_gathers_chi_rows_without_relayout(one_chip):
-    """The CHI row gather goes through a 2-D view: gathering rows of the
-    4-D (N, 17, 17, 17) table relays out all of it (9.3 GB of temporaries
-    at paper scale), which does not fit beside the resident masks."""
+@pytest.mark.parametrize("n,grid", [(PAPER_MASKS, G), (9000, G),
+                                    (9000, G // 2)])
+def test_device_bounds_step_gathers_chi_rows_without_relayout(n, grid,
+                                                              one_chip):
+    """The CP bounds step reads the 16 entries each candidate needs at
+    ``(pos, i, j, k)`` of the resident table: its program holds no value
+    of the table's size but the parameter, neither the 4-D table nor a
+    2-D view of its rows, at the float cell's 9,000 rows, the paper's
+    22,275, and over a coarse tier table.  A gather of whole rows, even
+    through a 2-D view, reshaped, copied and gathered the whole table
+    (361 MB of temporaries at 9,000 rows, 893 MB at 22,275); gathering
+    rows of the 4-D table directly relays out all of it (9.3 GB at paper
+    scale), which does not fit beside the resident masks.  A tier table
+    small enough for the chip's on-core memory may be prefetched there
+    whole by the compiler (``copy-done`` into memory space 1); in HBM
+    nothing of its size is made."""
     from repro.core.backend import _device_cp_bounds
 
     def s(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-    edges = s((G + 1,), jnp.int32)
+    edges = s((grid + 1,), jnp.int32)
     compiled = _device_cp_bounds.lower(
-        s((PAPER_MASKS, G + 1, G + 1, NB + 1), jnp.int32),
-        s((PAPER_MASKS,), jnp.int32), s((PAPER_MASKS, 4), jnp.int32),
+        s((n, grid + 1, grid + 1, NB + 1), jnp.int32),
+        s((n,), jnp.int32), s((n, 4), jnp.int32),
         edges, edges, s((4,), jnp.int32)).compile()
-    assert compiled.memory_analysis().temp_size_in_bytes < 2e9
+    side = grid + 1
+    table = re.compile(r"= s32\[%d,(%d|%d,%d,%d)\]\{([^}]*)\} ([\w-]+)\("
+                       % (n, side * side * (NB + 1), side, side, NB + 1))
+    values = table.findall(compiled.as_text())
+    assert values
+    for shape, layout, op in values:
+        assert "," in shape, "a 2-D view of the table's rows"
+        assert op == "parameter" or (op == "copy-done" and "S(1)" in layout)
+    assert compiled.memory_analysis().temp_size_in_bytes < 16e6
